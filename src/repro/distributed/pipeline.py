@@ -9,25 +9,9 @@ tests/test_pipeline.py on a fake 8-device mesh.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.6
-    shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x: no rep-varying tracking — disable the
-    # replication checker instead of pcast-marking the carries
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    shard_map = functools.partial(_shard_map_legacy, check_rep=False)
-
-
-def _pcast_varying(x, axis):
-    """Mark x device-varying over axis (no-op on jax without lax.pcast)."""
-    pcast = getattr(jax.lax, "pcast", None)
-    return pcast(x, (axis,), to="varying") if pcast else x
 
 
 def pipeline_forward(stage_fn, params_stacked, x_microbatches, mesh, *,
@@ -52,8 +36,8 @@ def pipeline_forward(stage_fn, params_stacked, x_microbatches, mesh, *,
         buf = jnp.zeros_like(xs_local[0])
         outs = jnp.zeros((n_micro,) + xs_local.shape[1:], xs_local.dtype)
         # carries become device-varying over the pp axis inside the loop
-        buf = _pcast_varying(buf, axis)
-        outs = _pcast_varying(outs, axis)
+        buf = jax.lax.pcast(buf, (axis,), to="varying")
+        outs = jax.lax.pcast(outs, (axis,), to="varying")
 
         def tick(carry, t):
             buf, outs = carry
@@ -86,10 +70,11 @@ def pipeline_forward(stage_fn, params_stacked, x_microbatches, mesh, *,
         )
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
     )
-    return fn(params_stacked, x_microbatches)
+    with jax.set_mesh(mesh):
+        return jax.jit(fn)(params_stacked, x_microbatches)

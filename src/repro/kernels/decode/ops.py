@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.decode.decode import (
-    _LANES,
     decode_fwd_pallas,
     paged_decode_fwd_pallas,
 )
@@ -48,13 +47,11 @@ def decode_attention_pallas(
     q3 = q.reshape(B, Hkv, group, D).reshape(B * Hkv, group, D)
     k3 = jnp.pad(k_cache, ((0, 0), (0, 0), (0, pk), (0, 0))).reshape(B * Hkv, S + pk, D)
     v3 = jnp.pad(v_cache, ((0, 0), (0, 0), (0, pk), (0, 0))).reshape(B * Hkv, S + pk, Dv)
-    len2 = jnp.broadcast_to(lengths.astype(jnp.int32)[:, None], (B, _LANES))
     o3 = decode_fwd_pallas(
-        q3, k3, v3, len2,
+        lengths, q3, k3, v3,
         scale=scale,
         variant=variant,
         block_k=bk,
-        num_q_heads=H,
         num_kv_heads=Hkv,
         interpret=interpret,
     )
@@ -93,16 +90,14 @@ def quant_decode_attention_pallas(
 
     def flat_scale(s):  # padded scale rows dequantize to exact zeros
         return jnp.pad(s, ((0, 0), (0, 0), (0, pk))).reshape(
-            B * Hkv, S + pk).astype(jnp.float32)
+            B * Hkv, 1, S + pk).astype(jnp.float32)
 
-    len2 = jnp.broadcast_to(lengths.astype(jnp.int32)[:, None], (B, _LANES))
     o3 = decode_fwd_pallas(
-        q3, flat(k_codes, D), flat(v_codes, Dv), len2,
+        lengths, q3, flat(k_codes, D), flat(v_codes, Dv),
         flat_scale(k_scale), flat_scale(v_scale),
         scale=scale,
         variant=variant,
         block_k=bk,
-        num_q_heads=H,
         num_kv_heads=Hkv,
         interpret=interpret,
     )
@@ -114,10 +109,8 @@ def quant_decode_attention_pallas(
 # ---------------------------------------------------------------------------
 def _paged_operands(q, pool_tokens, page_size, Hkv):
     B, H, D = q.shape
-    group = H // Hkv
     assert pool_tokens % page_size == 0, (pool_tokens, page_size)
-    q3 = q.reshape(B, Hkv, group, D).reshape(B * Hkv, group, D)
-    return q3, pool_tokens // page_size
+    return q.reshape(B, Hkv, H // Hkv, D), pool_tokens // page_size
 
 
 def fused_paged_decode_attention_pallas(
@@ -142,19 +135,18 @@ def fused_paged_decode_attention_pallas(
     Dv = v_pool.shape[-1]
     interpret = _interpret_default(interpret)
     scale = float(1.0 / np.sqrt(D)) if scale is None else float(scale)
-    q3, nblk = _paged_operands(q, pool_tokens, page_size, Hkv)
-    o3 = paged_decode_fwd_pallas(
-        block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q3,
+    q4, nblk = _paged_operands(q, pool_tokens, page_size, Hkv)
+    o4 = paged_decode_fwd_pallas(
+        block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q4,
         k_pool.reshape(nblk, page_size, Hkv, D),
         v_pool.reshape(nblk, page_size, Hkv, Dv),
         scale=scale,
         variant=variant,
         page_size=page_size,
         window=window,
-        num_kv_heads=Hkv,
         interpret=interpret,
     )
-    return o3.reshape(B, Hkv, H // Hkv, Dv).reshape(B, H, Dv)
+    return o4.reshape(B, H, Dv)
 
 
 def quant_fused_paged_decode_attention_pallas(
@@ -182,9 +174,9 @@ def quant_fused_paged_decode_attention_pallas(
     Dv = v_code_pool.shape[-1]
     interpret = _interpret_default(interpret)
     scale = float(1.0 / np.sqrt(D)) if scale is None else float(scale)
-    q3, nblk = _paged_operands(q, pool_tokens, page_size, Hkv)
-    o3 = paged_decode_fwd_pallas(
-        block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q3,
+    q4, nblk = _paged_operands(q, pool_tokens, page_size, Hkv)
+    o4 = paged_decode_fwd_pallas(
+        block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q4,
         k_code_pool.reshape(nblk, page_size, Hkv, D),
         v_code_pool.reshape(nblk, page_size, Hkv, Dv),
         k_scale_pool.reshape(nblk, page_size, Hkv).astype(jnp.float32),
@@ -193,10 +185,9 @@ def quant_fused_paged_decode_attention_pallas(
         variant=variant,
         page_size=page_size,
         window=window,
-        num_kv_heads=Hkv,
         interpret=interpret,
     )
-    return o3.reshape(B, Hkv, H // Hkv, Dv).reshape(B, H, Dv)
+    return o4.reshape(B, H, Dv)
 
 
 # ---------------------------------------------------------------------------

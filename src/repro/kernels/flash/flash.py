@@ -21,18 +21,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU scratch memory spaces; interpret mode accepts them too
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash.tile import (
     LANES as _LANES,
-    MASK_VALUE,
     finalize_tiles,
+    init_tiles,
     online_softmax_tile,
 )
 
@@ -60,9 +54,7 @@ def _fwd_kernel(
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, MASK_VALUE)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        init_tiles(m_scr, l_scr, acc_scr)
 
     r0 = qi * block_q
     c0 = ki * block_k
@@ -89,7 +81,7 @@ def _fwd_kernel(
 
     @pl.when(ki == nk - 1)
     def _fin():
-        finalize_tiles(o_ref, l_scr, acc_scr)
+        finalize_tiles(o_ref.at[0], l_scr, acc_scr)
 
 
 @functools.partial(
@@ -151,9 +143,9 @@ def flash_fwd_pallas(
         out_specs=pl.BlockSpec((1, block_q, D), q_map),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q3.dtype),
         scratch_shapes=[
-            _VMEM((block_q, _LANES), jnp.float32),
-            _VMEM((block_q, _LANES), jnp.float32),
-            _VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
     )(q3, k3, v3)

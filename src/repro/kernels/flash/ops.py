@@ -17,7 +17,6 @@ from repro.kernels.flash.prefill import (
     paged_prefill_fwd_pallas,
     prefill_fwd_pallas,
 )
-from repro.kernels.flash.tile import LANES as _LANES
 from repro.kernels.paged import gather_rows
 
 
@@ -83,11 +82,11 @@ def _fold(x, target):
     return _pad_seq(x, target).reshape((B * Hkv, target) + x.shape[3:])
 
 
-def _meta2(lengths, n_valid):
-    B = lengths.shape[0]
-    meta = jnp.zeros((B, _LANES), jnp.int32)
-    return meta.at[:, 0].set(lengths.astype(jnp.int32)).at[:, 1].set(
-        n_valid.astype(jnp.int32))
+def _meta(lengths, n_valid):
+    """(B, 2) int32 [cache length, chunk n_valid]: the scalar-prefetch
+    operand every prefill kernel masks from."""
+    return jnp.stack([lengths.astype(jnp.int32),
+                      n_valid.astype(jnp.int32)], axis=1)
 
 
 def _prefill_blocks(S, C, block_q, block_k):
@@ -136,7 +135,7 @@ def prefill_attention_pallas(
     bq, Cq, bk, Sp, Ck = _prefill_blocks(S, C, block_q, block_k)
     q3 = _pad_seq(q, Cq).reshape(B * H, Cq, D)
     o3 = prefill_fwd_pallas(
-        _meta2(lengths, n_valid), q3,
+        _meta(lengths, n_valid), q3,
         _fold(k_cache, Sp), _fold(v_cache, Sp),
         _fold(k_chunk, Ck), _fold(v_chunk, Ck),
         scale=scale, variant=variant, window=window, rolling=rolling,
@@ -179,10 +178,10 @@ def quant_prefill_attention_pallas(
     q3 = _pad_seq(q, Cq).reshape(B * H, Cq, D)
 
     def fscale(s, target):  # padded scale rows dequantize to exact zeros
-        return _fold(s, target).astype(jnp.float32)
+        return _fold(s, target)[:, None, :].astype(jnp.float32)
 
     o3 = prefill_fwd_pallas(
-        _meta2(lengths, n_valid), q3,
+        _meta(lengths, n_valid), q3,
         _fold(kc_codes, Sp), _fold(vc_codes, Sp),
         _fold(kn_codes, Ck), _fold(vn_codes, Ck),
         fscale(kc_scale, Sp), fscale(vc_scale, Sp),
@@ -194,9 +193,11 @@ def quant_prefill_attention_pallas(
     return o3.reshape(B, H, Cq, Dv)[:, :, :C, :]
 
 
-def _paged_chunk_pad(x, page_size):
+def _paged_chunk(x, page_size):
+    """(B, Hkv, C, ·) chunk -> token-major (B, C_pad, Hkv, ·), the pool's
+    own layout, padded to whole pages."""
     C = x.shape[2]
-    return _fold(x, C + (-C) % page_size)
+    return jnp.moveaxis(_pad_seq(x, C + (-C) % page_size), 1, 2)
 
 
 def fused_paged_prefill_attention_pallas(
@@ -229,19 +230,17 @@ def fused_paged_prefill_attention_pallas(
     assert pool_tokens % page_size == 0, (pool_tokens, page_size)
     nblk = pool_tokens // page_size
     bq = min(block_q, C)
-    q3 = _pad_seq(q, C + (-C) % bq).reshape(B * H, C + (-C) % bq, D)
-    meta = jnp.stack([lengths.astype(jnp.int32),
-                      n_valid.astype(jnp.int32)], axis=1)
-    o3 = paged_prefill_fwd_pallas(
-        block_tables.astype(jnp.int32), meta, q3,
+    o4 = paged_prefill_fwd_pallas(
+        block_tables.astype(jnp.int32), _meta(lengths, n_valid),
+        _pad_seq(q, C + (-C) % bq),
         k_pool.reshape(nblk, page_size, Hkv, D),
         v_pool.reshape(nblk, page_size, Hkv, Dv),
-        _paged_chunk_pad(k_chunk, page_size),
-        _paged_chunk_pad(v_chunk, page_size),
+        _paged_chunk(k_chunk, page_size),
+        _paged_chunk(v_chunk, page_size),
         scale=scale, variant=variant, window=window, page_size=page_size,
-        block_q=bq, num_q_heads=H, num_kv_heads=Hkv, interpret=interpret,
+        block_q=bq, interpret=interpret,
     )
-    return o3.reshape(B, H, -1, Dv)[:, :, :C, :]
+    return o4[:, :, :C, :]
 
 
 def quant_fused_paged_prefill_attention_pallas(
@@ -279,23 +278,21 @@ def quant_fused_paged_prefill_attention_pallas(
     assert pool_tokens % page_size == 0, (pool_tokens, page_size)
     nblk = pool_tokens // page_size
     bq = min(block_q, C)
-    q3 = _pad_seq(q, C + (-C) % bq).reshape(B * H, C + (-C) % bq, D)
-    meta = jnp.stack([lengths.astype(jnp.int32),
-                      n_valid.astype(jnp.int32)], axis=1)
-    o3 = paged_prefill_fwd_pallas(
-        block_tables.astype(jnp.int32), meta, q3,
+    o4 = paged_prefill_fwd_pallas(
+        block_tables.astype(jnp.int32), _meta(lengths, n_valid),
+        _pad_seq(q, C + (-C) % bq),
         k_code_pool.reshape(nblk, page_size, Hkv, D),
         v_code_pool.reshape(nblk, page_size, Hkv, Dv),
-        _paged_chunk_pad(kn_codes, page_size),
-        _paged_chunk_pad(vn_codes, page_size),
+        _paged_chunk(kn_codes, page_size),
+        _paged_chunk(vn_codes, page_size),
         k_scale_pool.reshape(nblk, page_size, Hkv).astype(jnp.float32),
         v_scale_pool.reshape(nblk, page_size, Hkv).astype(jnp.float32),
-        _paged_chunk_pad(kn_scale, page_size).astype(jnp.float32),
-        _paged_chunk_pad(vn_scale, page_size).astype(jnp.float32),
+        _paged_chunk(kn_scale, page_size).astype(jnp.float32),
+        _paged_chunk(vn_scale, page_size).astype(jnp.float32),
         scale=scale, variant=variant, window=window, page_size=page_size,
-        block_q=bq, num_q_heads=H, num_kv_heads=Hkv, interpret=interpret,
+        block_q=bq, interpret=interpret,
     )
-    return o3.reshape(B, H, -1, Dv)[:, :, :C, :]
+    return o4[:, :, :C, :]
 
 
 def paged_prefill_attention_pallas(

@@ -30,8 +30,9 @@ def online_softmax_tile(q, k, v, k_scale, v_scale, mask,
     """One KV tile of the online-softmax recurrence (shared by all kernels).
 
     q: (rows, D) f32; k: (bk, D) f32 values — or raw codes when ``k_scale``
-    is given; v: (bk, Dv) values or codes; k_scale/v_scale: (bk,) f32
-    per-row scales or None; mask: (rows, bk) bool of valid columns.
+    is given; v: (bk, Dv) values or codes; k_scale/v_scale: (1, bk) f32
+    per-row scales laid along lanes (one per KV column), or None; mask:
+    (rows, bk) bool of valid columns.
 
     Quantized fusion: scores take one column rescale after the q·codes
     matmul, and the value matmul folds the scale into the probability tile
@@ -43,7 +44,7 @@ def online_softmax_tile(q, k, v, k_scale, v_scale, mask,
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
     if k_scale is not None:
-        s = s * k_scale[None, :]
+        s = s * k_scale
     s = jnp.where(mask, s, MASK_VALUE)
     m_prev = m_scr[...][:, :1]
     l_prev = l_scr[...][:, :1]
@@ -52,7 +53,7 @@ def online_softmax_tile(q, k, v, k_scale, v_scale, mask,
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = p if v_scale is None else p * v_scale[None, :]
+        pv = p if v_scale is None else p * v_scale
         acc = acc_scr[...] * alpha + jax.lax.dot_general(
             pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -63,7 +64,7 @@ def online_softmax_tile(q, k, v, k_scale, v_scale, mask,
         lr = log2exp_lhat(m_prev - m_new)
         p = jnp.where(mask, pow2_neg(log2exp_lhat(s - m_new), jnp.float32), 0.0)
         l_new = apply_pow2_scale(l_prev, lr) + jnp.sum(p, axis=1, keepdims=True)
-        pv = p if v_scale is None else p * v_scale[None, :]
+        pv = p if v_scale is None else p * v_scale
         acc = apply_pow2_scale(
             acc_scr[...], jnp.broadcast_to(lr, acc_scr.shape)
         ) + jax.lax.dot_general(
@@ -76,8 +77,16 @@ def online_softmax_tile(q, k, v, k_scale, v_scale, mask,
     acc_scr[...] = acc
 
 
+def init_tiles(m_scr, l_scr, acc_scr):
+    """Empty online-softmax state, before a row block's first KV tile."""
+    m_scr[...] = jnp.full_like(m_scr, MASK_VALUE)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
 def finalize_tiles(o_ref, l_scr, acc_scr):
-    """acc / l into the output ref; fully-masked rows yield 0, never NaN."""
+    """acc / l into ``o_ref`` (a (rows, Dv) view of the output block);
+    fully-masked rows yield 0, never NaN."""
     l = l_scr[...][:, :1]
     l = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+    o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)

@@ -69,6 +69,8 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
+
 
 @dataclasses.dataclass(frozen=True)
 class AttentionSpec:
@@ -233,11 +235,7 @@ def resolved_backends(spec: AttentionSpec, *, paged: bool = False) -> list[dict]
             ("paged prefill", spec.resolved_paged_impl()),
             ("paged decode", spec.resolved_paged_impl()),
         ]
-    try:
-        import jax
-        on_cpu = jax.default_backend() == "cpu"
-    except Exception:  # pragma: no cover
-        on_cpu = False
+    on_cpu = jax.default_backend() == "cpu"
     out = []
     for kind, name in kinds:
         resolved = _FALLBACK_NOTES.get((kind, name), name)

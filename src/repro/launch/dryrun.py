@@ -22,7 +22,7 @@ import jax
 
 from repro.configs import SHAPES, cells_for, get_config
 from repro.configs.shapes import SUBQUADRATIC_ARCHS
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import analyze, model_flops_per_device
 from repro.models.api import decode_step, forward, init_decode_state
 from repro.models.inputs import input_specs
@@ -61,7 +61,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
     n_dev = mesh.size
     t0 = time.time()
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt = adamw(3e-4, moment_dtype=cfg.opt_state_dtype)
             state_shapes = make_train_state_specs(cfg, opt)

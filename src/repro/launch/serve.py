@@ -35,6 +35,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.api import init_model
 from repro.serve.engine import ServeEngine, validate_kv_dtype
 
@@ -111,6 +112,7 @@ def main(argv=None):
     if args.prefix_cache and args.kv_layout != "paged":
         ap.error("--prefix-cache requires --kv-layout paged: the contiguous "
                  "layout has no shared physical blocks to dedupe")
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke, dtype="float32",
                      param_dtype="float32", attention_variant=args.variant)

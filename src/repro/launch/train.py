@@ -16,11 +16,8 @@ import logging
 import time
 
 import jax
-
-from repro.launch.mesh import set_mesh
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.restore import latest_step, restore_checkpoint
 from repro.checkpoint.save import AsyncCheckpointer
@@ -29,23 +26,34 @@ from repro.data.sharded_loader import ShardedLoader
 from repro.data.synthetic import SyntheticLMDataset
 from repro.distributed.compression import error_feedback_int8, init_residuals
 from repro.distributed.fault import FaultInjector, StragglerWatchdog, TrainSupervisor
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models.api import init_model
 from repro.optim.adamw import adamw
 from repro.optim.schedule import cosine_schedule
 from repro.sharding.rules import state_shardings
-from repro.train.step import build_train_step, make_train_state_specs
+from repro.train.step import (
+    build_train_step,
+    make_train_state,
+    make_train_state_specs,
+)
 
 log = logging.getLogger("repro.train")
 
 
-def make_mesh_for_host():
-    n = len(jax.devices())
-    if n >= 4:
-        return jax.make_mesh((n // 2, 2), ("data", "model"))
-    return jax.make_mesh((n, 1), ("data", "model"))
+def make_mesh_for_host(devices=None):
+    """(data, model) mesh over ``devices`` (default: all of this host's):
+    two-way tensor parallel from four devices up, data parallel otherwise."""
+    devices = jax.devices() if devices is None else list(devices)
+    n = len(devices)
+    shape = (n // 2, 2) if n >= 4 else (n, 1)
+    return make_mesh(shape, ("data", "model"), devices=devices)
 
 
-def main(argv=None, cfg_override=None):
+def main(argv=None, cfg_override=None, mesh=None):
+    """Train; returns the per-step losses. ``mesh`` (default: a mesh over
+    every device of this host) lets a caller run the same steps on another
+    device set, such as one chip of a four-chip host."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--smoke", action="store_true", help="reduced config")
@@ -62,6 +70,7 @@ def main(argv=None, cfg_override=None):
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
+    use_compile_cache()
 
     overrides = {"dtype": "float32", "param_dtype": "float32"}
     if args.variant:
@@ -70,7 +79,7 @@ def main(argv=None, cfg_override=None):
         cfg = cfg_override.replace(**overrides)
     else:
         cfg = get_config(args.arch, smoke=args.smoke, **overrides)
-    mesh = make_mesh_for_host()
+    mesh = make_mesh_for_host() if mesh is None else mesh
     opt = adamw(cosine_schedule(args.lr, 20, args.steps),
                 moment_dtype=cfg.opt_state_dtype)
 
@@ -92,15 +101,20 @@ def main(argv=None, cfg_override=None):
         grad_transform=grad_transform if args.compress_grads else None,
     )
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state_shapes = make_train_state_specs(cfg, opt)
         st_sh = state_shardings(state_shapes, mesh)
-        jit_step = jax.jit(step_fn_inner, donate_argnums=(0,))
+        # the state lives where the sharding rules put it, for a fresh start
+        # as for a restored checkpoint; it is created there, so no device
+        # ever holds a whole copy
+        jit_step = jax.jit(step_fn_inner, out_shardings=(st_sh, None),
+                           donate_argnums=(0,))
 
-        params = init_model(jax.random.PRNGKey(0), cfg)
-        state = {"params": params, "opt": opt.init(params)}
+        state = jax.jit(
+            lambda key: make_train_state(init_model(key, cfg), opt),
+            out_shardings=st_sh)(jax.random.PRNGKey(0))
         if args.compress_grads:
-            residuals_holder["res"] = init_residuals(params)
+            residuals_holder["res"] = init_residuals(state["params"])
 
         start = 0
         ckpt = AsyncCheckpointer(args.ckpt_dir, keep=3) if args.ckpt_dir else None

@@ -23,11 +23,6 @@ import jax.numpy as jnp
 
 from repro.layers.common import activation_fn, dense_init
 
-try:  # jax >= 0.6
-    shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
-
 
 def moe_init(key, cfg, dtype):
     m = cfg.moe
@@ -94,7 +89,7 @@ def _expert_ffn_ep(params, xe, cfg):
         return jnp.einsum("cef,efd->ced", up, wd)
 
     wg_arg = params["w_gate"] if gated else params["w_up"]
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P("model", dp, None), P("model", dp, None),

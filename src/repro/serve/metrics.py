@@ -418,9 +418,13 @@ class MetricsRegistry:
 
 # -- kernel dispatch counters (DESIGN.md §12) --------------------------------
 
-def _spec_labels(kind: str, spec, layout: str) -> dict:
+def _spec_labels(kind: str, spec, layout: str, page_size: int) -> dict:
     """The per-AttentionSpec counter key: which table was dispatched, the
-    backend that resolved, and the numerics axes that price it."""
+    backend that ran, and the numerics axes that price it. A fused paged
+    backend dispatched without block tables (``page_size`` 0) runs its
+    gather-then-kernel form, and is counted as ``gather_<impl>``."""
+    from repro.kernels import costs
+
     impl = {
         "full": spec.resolved_impl,
         "prefill": spec.resolved_prefill_impl,
@@ -428,6 +432,8 @@ def _spec_labels(kind: str, spec, layout: str) -> dict:
         "paged_prefill": spec.resolved_paged_impl,
         "paged_decode": spec.resolved_paged_impl,
     }[kind]()
+    if layout == "paged" and not page_size and costs.impl_path(impl) == "fused":
+        impl = "gather_" + impl
     return {"kind": kind, "impl": impl, "variant": spec.variant,
             "kv_dtype": spec.kv_dtype, "layout": layout}
 
@@ -447,7 +453,7 @@ def make_dispatch_sink(registry: MetricsRegistry):
              d_qk: int, d_v: int, kv_tokens: int, q_tokens: int,
              page_size: int = 0):
         layout = "paged" if kind.startswith("paged") else "contiguous"
-        labels = _spec_labels(kind, spec, layout)
+        labels = _spec_labels(kind, spec, layout, page_size)
         path = costs.impl_path(labels["impl"])
         registry.counter("attention_dispatch_total", **labels).inc()
         if kind in ("decode", "paged_decode"):

@@ -15,13 +15,8 @@ from jax.sharding import PartitionSpec as P
 
 
 def _current_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return None
-    if m is None or not m.axis_names:
-        return None
-    return m
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def constrain(x, *dims):

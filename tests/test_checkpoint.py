@@ -10,6 +10,7 @@ import pytest
 
 from repro.checkpoint.restore import latest_step, restore_checkpoint
 from repro.checkpoint.save import AsyncCheckpointer, save_checkpoint
+from repro.launch.mesh import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
@@ -29,7 +30,7 @@ def test_roundtrip_single_device(tmp_path):
     save_checkpoint(tree, str(tmp_path), 7)
     assert latest_step(str(tmp_path)) == 7
     shapes = jax.tree.map(lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype), tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     shardings = jax.tree.map(lambda l: NamedSharding(mesh, P()), tree)
     restored, step = restore_checkpoint(shapes, shardings, str(tmp_path))
     assert step == 7
@@ -43,7 +44,7 @@ def test_async_checkpointer_matches_sync(tmp_path):
     ck.save(tree, 10)
     ck.wait()
     shapes = jax.tree.map(lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype), tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     shardings = jax.tree.map(lambda l: NamedSharding(mesh, P()), tree)
     restored, step = restore_checkpoint(shapes, shardings, str(tmp_path))
     assert step == 10
@@ -68,15 +69,16 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint.save import save_checkpoint
 from repro.checkpoint.restore import restore_checkpoint
+from repro.launch.mesh import make_mesh
 
 base = sys.argv[1]
-mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+mesh1 = make_mesh((4, 2), ("data", "model"))
 w = jnp.arange(64 * 32, dtype=jnp.float32).reshape(64, 32)
 w1 = jax.device_put(w, NamedSharding(mesh1, P("data", "model")))
 save_checkpoint({"w": w1}, base, 5)
 
 # restore on a DIFFERENT mesh layout (elastic)
-mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+mesh2 = make_mesh((2, 4), ("data", "model"))
 shapes = {"w": jax.ShapeDtypeStruct((64, 32), jnp.float32)}
 sh2 = {"w": NamedSharding(mesh2, P("model", "data"))}
 restored, step = restore_checkpoint(shapes, sh2, base)

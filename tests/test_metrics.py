@@ -194,6 +194,37 @@ def test_eager_dispatch_counters_per_spec():
         install_dispatch_counters(None)
 
 
+def test_tableless_paged_dispatch_counts_as_gather():
+    """A fused paged backend dispatched without block tables runs its
+    gather-then-kernel form, and the counters say so."""
+    from repro.kernels.paged import slot_rows
+    from repro.kernels.registry import dispatch_paged_decode
+
+    m = MetricsRegistry()
+    install_dispatch_counters(m)
+    try:
+        rng = np.random.default_rng(0)
+        B, H, Hkv, D, ps, nblk = 1, 2, 1, 8, 4, 4
+        q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
+        pool = jnp.asarray(rng.standard_normal((nblk * ps, Hkv, D)),
+                           jnp.float32)
+        bt = jnp.asarray([[2, 0, 3, 1]], jnp.int32)
+        lengths = jnp.asarray([13], jnp.int32)
+        spec = AttentionSpec(impl="pallas")
+        rows = slot_rows(bt, ps)
+        dispatch_paged_decode(spec, q, pool, pool, rows, lengths)
+        dispatch_paged_decode(spec, q, pool, pool, rows, lengths,
+                              block_tables=bt, page_size=ps)
+        common = dict(kind="paged_decode", variant="exact", kv_dtype="fp32",
+                      layout="paged")
+        assert m.counter_value("attention_dispatch_total",
+                               impl="gather_pallas", **common) == 1
+        assert m.counter_value("attention_dispatch_total", impl="pallas",
+                               **common) == 1
+    finally:
+        install_dispatch_counters(None)
+
+
 def test_engine_exec_ledger_matches_steps(traced_run):
     """The executed-cost ledger prices every engine step exactly once,
     keyed by the resolved impl the engine dispatches."""

@@ -10,8 +10,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.distributed.pipeline import pipeline_forward
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4,), ("pp",))
+mesh = make_mesh((4,), ("pp",))
 n_stages, n_micro, mb, d = 4, 8, 2, 16
 key = jax.random.PRNGKey(0)
 ws = jax.random.normal(key, (n_stages, d, d)) * 0.3

@@ -75,7 +75,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from jax.sharding import PartitionSpec as P
 from repro.configs import get_config
-from repro.launch.mesh import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.roofline import analyze, model_flops_per_device
 from repro.configs.shapes import ShapeSpec
 from repro.models.inputs import input_specs
@@ -84,9 +84,9 @@ from repro.train.step import build_train_step, make_train_state_specs
 from repro.optim.adamw import adamw
 
 cfg = get_config("qwen2-0.5b", smoke=True)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 opt = adamw(1e-3)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     shapes = make_train_state_specs(cfg, opt)
     st_sh = state_shardings(shapes, mesh)
     b_shapes = input_specs(cfg, seq_len=64, global_batch=8, kind="train")
