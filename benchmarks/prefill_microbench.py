@@ -52,9 +52,8 @@ from repro.kernels.registry import (
     dispatch_prefill,
     resolved_backends,
 )
-# the analytic cost model lives in repro.kernels.costs since DESIGN.md §12
-# (shared with the dispatch counters and the engine's executed-cost
-# ledger); re-exported here so existing callers keep their import path
+# the analytic cost model lives in repro.kernels.costs since DESIGN.md §12;
+# re-exported here so existing callers keep their import path
 from repro.kernels.costs import analytic_bytes_per_chunk_token  # noqa: F401
 from repro.numerics.quant import QuantKV, quantize_kv
 

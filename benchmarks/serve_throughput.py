@@ -432,9 +432,6 @@ def bench_run(params, cfg0, variant, kv_layout, kv_dtype, *, slots,
                              / max(t_decode, 1e-9)),
         "first_token_steps": max(r.first_token_step for r in reqs),
         "legacy_first_token_steps": max(len(p) for p in prompts),
-        # the executed-cost attention ledger: analytic HBM bytes/FLOPs the
-        # run's steps were designed to move, per dispatch kind
-        "attention_exec": snap["attention"],
     }
     r.update(_percentile_cols(snap))
     r.update(snap["memory"])

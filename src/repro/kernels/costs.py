@@ -7,17 +7,11 @@ the kernel actually touches; on the CPU software proxy wall-clock ranks
 backends wrongly, so analytic bytes are the tracked metric).
 
 These helpers started life inside ``benchmarks/decode_microbench.py`` /
-``benchmarks/prefill_microbench.py`` and moved here so three layers can
-share one definition:
-
-  * the microbenches (``analytic_bytes_per_ctx_token`` /
-    ``analytic_bytes_per_chunk_token`` keep their exact signatures and
-    semantics — BENCH_decode.json / BENCH_prefill.json numbers are
-    unchanged);
-  * the ``repro.kernels.registry`` dispatch counters (shape-level cost
-    per dispatched call, ``serve/metrics.py``);
-  * the ``ServeEngine`` executed-cost ledger (actual host-side lengths
-    per engine step — the live fused-vs-gather byte ledger).
+``benchmarks/prefill_microbench.py``, which still report them
+(``analytic_bytes_per_ctx_token`` / ``analytic_bytes_per_chunk_token``
+keep their exact signatures and semantics — BENCH_decode.json /
+BENCH_prefill.json numbers are unchanged). ``impl_path`` also labels the
+dispatch counters of ``serve/metrics.py``.
 
 Cost conventions (documented per helper): q/output traffic is excluded
 (identical across paths), gather datapaths pay a write + read of the
@@ -127,35 +121,3 @@ def analytic_bytes_per_chunk_token(layout, kv_dtype, path, *, Hkv, D, Dv,
     elif path == "gather" and kv_dtype != "fp32":
         b += copy
     return b / chunk
-
-
-def analytic_attention_flops(q_tokens, kv_tokens, *, heads, d_qk, d_v):
-    """Attention-math FLOPs for ``q_tokens`` queries over ``kv_tokens``
-    keys/values: 2·D_qk per (q, k) score pair + 2·D_v per weighted-sum
-    pair, per head — the standard estimate, masking ignored (an upper
-    bound within 2x for causal chunks, exact for decode)."""
-    return 2 * heads * (d_qk + d_v) * int(q_tokens) * int(kv_tokens)
-
-
-def attn_kv_geometry(cfg) -> dict:
-    """Per-attention-layer KV geometry of a model config, in the shape the
-    analytic helpers take.
-
-    GQA/MHA layers price ``Hkv`` heads of ``D``-dim K plus ``Dv``-dim V
-    rows per token; MLA stores one latent row of ``kv_lora_rank +
-    qk_rope_dim`` features per token (priced as a single ``Hkv=1`` head
-    with ``Dv=0`` — matching ``serve.paged.kv_token_bytes``). ``layers``
-    counts the attention layers sharing that geometry; recurrent kinds
-    hold no KV and are excluded.
-    """
-    layers = sum(1 for k in cfg.pattern_for() if k == "attn")
-    if cfg.mla is not None:
-        m = cfg.mla
-        # d_qk/d_v are the *attention-math* per-head dims (post latent
-        # expansion) — used for FLOPs; D/Dv price the stored bytes
-        return {"Hkv": 1, "D": m.kv_lora_rank + m.qk_rope_dim,
-                "Dv": 0, "heads": cfg.num_heads, "layers": layers,
-                "d_qk": m.qk_nope_dim + m.qk_rope_dim, "d_v": m.v_head_dim}
-    d = cfg.resolved_head_dim()
-    return {"Hkv": cfg.num_kv_heads, "D": d, "Dv": d,
-            "heads": cfg.num_heads, "layers": layers, "d_qk": d, "d_v": d}

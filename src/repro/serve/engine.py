@@ -106,13 +106,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.costs import (
-    analytic_attention_flops,
-    analytic_bytes_per_chunk_token,
-    analytic_bytes_per_ctx_token,
-    attn_kv_geometry,
-    impl_path,
-)
 from repro.kernels.registry import AttentionSpec, resolved_backends
 
 from repro.models.api import (
@@ -297,9 +290,13 @@ class ServeEngine:
         # observability (DESIGN.md §12): the registry is the single owner
         # of every serving counter — memory_stats()/PoolStats are views.
         # ``trace`` gates span/event recording only; counters, histograms
-        # and metrics_snapshot() are always live.
+        # and metrics_snapshot() are always live, and every span is a
+        # profiler annotation, so the phases of a tick lie on the device
+        # trace's clock
         self.metrics = metrics if metrics is not None else MetricsRegistry(
             trace=trace)
+        if self.metrics.annotate is None:
+            self.metrics.annotate = jax.profiler.TraceAnnotation
         self.kv_dtype = validate_kv_dtype(cfg, kv_dtype)
         cfg = cfg.replace(kv_dtype=self.kv_dtype)
         if attention_impl is not None:
@@ -364,31 +361,34 @@ class ServeEngine:
                                   prefix_cache=self.prefix_cache,
                                   metrics=self.metrics)
             self.state = init_paged_state(cfg, slots, n_pool, ps)
-            self._cow_copy = jax.jit(
-                lambda state, src, dst: copy_paged_block(
-                    state, self.cfg, src, dst, page_size=ps)
-            )
-            self._decode = jax.jit(
-                lambda params, state, toks, lens, bt: decode_step_paged(
-                    params, state, toks, lens, bt, self.cfg, page_size=ps)
-            )
-            self._prefill = jax.jit(
-                lambda params, state, toks, lens, nv, bt: prefill_paged(
-                    params, state, toks, lens, nv, bt, self.cfg,
-                    page_size=ps)
-            )
+
+            # named, so the device trace's modules read jit_serve_*
+            def serve_cow_copy(state, src, dst):
+                return copy_paged_block(state, self.cfg, src, dst,
+                                        page_size=ps)
+
+            def serve_decode_step(params, state, toks, lens, bt):
+                return decode_step_paged(params, state, toks, lens, bt,
+                                         self.cfg, page_size=ps)
+
+            def serve_prefill_step(params, state, toks, lens, nv, bt):
+                return prefill_paged(params, state, toks, lens, nv, bt,
+                                     self.cfg, page_size=ps)
+
+            self._cow_copy = jax.jit(serve_cow_copy)
         else:
             self.page_size = 0
             self.pool = None
             self.state = init_decode_state(cfg, slots, max_len)
-            self._decode = jax.jit(
-                lambda params, state, toks, lens: decode_step(
-                    params, state, toks, lens, self.cfg)
-            )
-            self._prefill = jax.jit(
-                lambda params, state, toks, lens, nv: prefill(
-                    params, state, toks, lens, nv, self.cfg)
-            )
+
+            def serve_decode_step(params, state, toks, lens):
+                return decode_step(params, state, toks, lens, self.cfg)
+
+            def serve_prefill_step(params, state, toks, lens, nv):
+                return prefill(params, state, toks, lens, nv, self.cfg)
+
+        self._decode = jax.jit(serve_decode_step)
+        self._prefill = jax.jit(serve_prefill_step)
         self.lengths = np.zeros((slots,), np.int32)
         self.cur_tok = np.zeros((slots,), np.int32)
         self.requests: list[Request | None] = [None] * slots
@@ -433,45 +433,14 @@ class ServeEngine:
         self._g_queue = m.gauge("serve_queue_depth")
         # TTFT/TPOT: engine steps are the exact scheduling-level signal on
         # the CPU proxy; the ms twins are host wall clock (no device syncs
-        # beyond the per-tick host transfer the engine already performs)
+        # beyond the per-tick host transfer the engine already performs),
+        # TTFT from submit so that queue wait counts
         self._h_ttft_steps = m.histogram("serve_ttft_steps")
         self._h_tpot_steps = m.histogram("serve_tpot_steps")
         self._h_ttft_ms = m.histogram("serve_ttft_ms", buckets=MS_BUCKETS)
         self._h_tpot_ms = m.histogram("serve_tpot_ms", buckets=MS_BUCKETS)
         self._now = 0.0  # host timestamp taken once per tick
         m.name_track(PID_ENGINE, 0, "engine steps")
-        # executed-cost ledger (§12): each engine step is priced through
-        # the analytic helpers at its *actual* host-side lengths, keyed by
-        # the spec the engine dispatches — the live fused-vs-gather byte
-        # ledger. (The registry-level dispatch counters are installed
-        # globally: 1:1 for eager callers, per-trace under jit.)
-        spec = AttentionSpec.from_config(cfg)
-        self._geom = g = attn_kv_geometry(cfg)
-        layout = "paged" if self.paged else "contiguous"
-        self._exec = {}
-        for kind, impl in (
-                ("prefill", spec.resolved_paged_impl() if self.paged
-                 else spec.resolved_prefill_impl()),
-                ("decode", spec.resolved_paged_impl() if self.paged
-                 else spec.resolved_decode_impl())):
-            labels = {"kind": kind, "impl": impl, "variant": spec.variant,
-                      "kv_dtype": self.kv_dtype, "layout": layout}
-            self._exec[kind] = {
-                "impl": impl,
-                "path": impl_path(impl),
-                "calls": m.counter("attention_exec_calls_total", **labels),
-                "steps": m.counter("attention_exec_steps_total", **labels),
-                "tokens": m.counter("attention_exec_kv_tokens_total",
-                                    **labels),
-                "bytes": m.counter("attention_exec_analytic_bytes",
-                                   **labels),
-                "flops": m.counter("attention_exec_analytic_flops",
-                                   **labels),
-            }
-        self._decode_bytes_per_ctx_token = analytic_bytes_per_ctx_token(
-            layout, self.kv_dtype, self._exec["decode"]["path"],
-            Hkv=g["Hkv"], D=g["D"], Dv=g["Dv"],
-            page_size=self.page_size or 1)
         install_dispatch_counters(self.metrics)
         # constructor record (DESIGN.md §13): serve/snapshot.py rebuilds an
         # identically shaped engine from exactly these kwargs, so the
@@ -498,45 +467,46 @@ class ServeEngine:
         (None falls back to the engine defaults); an expired request
         finishes with ``finish_reason="deadline"`` and whatever tokens it
         produced."""
-        prompt = list(prompt)
-        if not prompt:
-            raise ValueError("empty prompt: a request needs at least one "
-                             "token to produce logits")
-        if len(prompt) > self.max_len - 1:
-            raise ValueError(
-                f"prompt length {len(prompt)} exceeds max_len - 1 = "
-                f"{self.max_len - 1} (one position must remain for the "
-                f"first sampled token)")
-        if max_new < 1:
-            raise ValueError(f"max_new must be >= 1, got {max_new}")
-        deadline_steps = (deadline_steps if deadline_steps is not None
-                          else self.default_deadline_steps)
-        deadline_s = (deadline_s if deadline_s is not None
-                      else self.default_deadline_s)
-        if deadline_steps is not None and deadline_steps < 1:
-            raise ValueError(
-                f"deadline_steps must be >= 1, got {deadline_steps}")
-        if deadline_s is not None and deadline_s < 0:
-            raise ValueError(f"deadline_s must be >= 0, got {deadline_s}")
-        if rid is None:
-            rid = self._next_rid
-        elif rid in self._rids:
-            raise ValueError(
-                f"duplicate rid {rid}: request ids must be unique per "
-                f"engine (auto-assignment never collides; explicit rids "
-                f"are the caller's responsibility)")
-        self._rids.add(rid)
-        self._next_rid = max(self._next_rid, rid + 1)
-        req = Request(rid, prompt, max_new, prefill_toks=list(prompt),
-                      deadline_steps=deadline_steps, deadline_s=deadline_s,
-                      submit_time=time.perf_counter())
-        if deadline_s is not None:
-            self.deadlines.arm(rid, wall_budget=deadline_s,
-                               wall_base=req.submit_time)
-        self.queue.append(req)
-        self._c_submitted.inc()
-        self._g_queue.set(len(self.queue))
-        return req
+        with self.metrics.span("serve.submit"):
+            prompt = list(prompt)
+            if not prompt:
+                raise ValueError("empty prompt: a request needs at least one "
+                                 "token to produce logits")
+            if len(prompt) > self.max_len - 1:
+                raise ValueError(
+                    f"prompt length {len(prompt)} exceeds max_len - 1 = "
+                    f"{self.max_len - 1} (one position must remain for the "
+                    f"first sampled token)")
+            if max_new < 1:
+                raise ValueError(f"max_new must be >= 1, got {max_new}")
+            deadline_steps = (deadline_steps if deadline_steps is not None
+                              else self.default_deadline_steps)
+            deadline_s = (deadline_s if deadline_s is not None
+                          else self.default_deadline_s)
+            if deadline_steps is not None and deadline_steps < 1:
+                raise ValueError(
+                    f"deadline_steps must be >= 1, got {deadline_steps}")
+            if deadline_s is not None and deadline_s < 0:
+                raise ValueError(f"deadline_s must be >= 0, got {deadline_s}")
+            if rid is None:
+                rid = self._next_rid
+            elif rid in self._rids:
+                raise ValueError(
+                    f"duplicate rid {rid}: request ids must be unique per "
+                    f"engine (auto-assignment never collides; explicit rids "
+                    f"are the caller's responsibility)")
+            self._rids.add(rid)
+            self._next_rid = max(self._next_rid, rid + 1)
+            req = Request(rid, prompt, max_new, prefill_toks=list(prompt),
+                          deadline_steps=deadline_steps, deadline_s=deadline_s,
+                          submit_time=time.perf_counter())
+            if deadline_s is not None:
+                self.deadlines.arm(rid, wall_budget=deadline_s,
+                                   wall_base=req.submit_time)
+            self.queue.append(req)
+            self._c_submitted.inc()
+            self._g_queue.set(len(self.queue))
+            return req
 
     def cancel(self, rid: int) -> bool:
         """Cancel a request wherever it is in its lifecycle: still queued,
@@ -792,14 +762,15 @@ class ServeEngine:
         first sample after a preemption spans the whole requeue + resume
         period. The ms twins reuse the per-tick host timestamp (one
         ``perf_counter`` per step, so values are quantized to tick starts
-        — no extra timestamps or device syncs on the token path)."""
+        — no extra timestamps or device syncs on the token path); ms TTFT
+        runs from ``submit()``, so it counts the wait in the queue."""
         req = self.requests[s]
         if req.first_token_step is None:
             req.first_token_step = self.ticks
             self._h_ttft_steps.record(req.first_token_step
                                       - req.admit_step + 1)
-            if req.admit_time is not None:
-                self._h_ttft_ms.record((self._now - req.admit_time) * 1e3)
+            if req.submit_time is not None:
+                self._h_ttft_ms.record((self._now - req.submit_time) * 1e3)
             if self.metrics.trace:
                 self.metrics.instant("first_token", pid=PID_REQUESTS,
                                      tid=req.rid, step=self.ticks)
@@ -1041,9 +1012,10 @@ class ServeEngine:
             self._release_slot(s, "failed")
         return survivors
 
-    def _prefill_tick(self, active):
-        """One chunked step: prefilling slots absorb up to chunk_size prompt
-        tokens; decode-ready slots ride along as 1-valid chunks."""
+    def _chunk_inputs(self, active):
+        """Host arrays of one chunked step: prefilling slots absorb up to
+        chunk_size prompt tokens; decode-ready slots ride along as 1-valid
+        chunks."""
         C = self.chunk_size
         toks = np.zeros((self.slots, C), np.int32)
         nv = np.zeros((self.slots,), np.int32)
@@ -1056,21 +1028,56 @@ class ServeEngine:
                 take = 1
                 toks[s, 0] = self.cur_tok[s]
             nv[s] = take
-        args = (self.params, self.state, jnp.asarray(toks),
-                jnp.asarray(self.lengths), jnp.asarray(nv))
-        if self.paged:
-            args += (self._block_tables(),)
-        logits, self.state = self._prefill(*args)
-        logits = self._chaos_logits(active, logits)
-        nxt = np.asarray(sample_tokens(self._sample_keys(), logits,
-                                       temperature=self.temperature))
+        return toks, nv
+
+    def _step(self, active, prefilling: bool):
+        """One engine step over the ``active`` slots: the chunked prefill
+        graph when ``prefilling``, else the single-token decode graph
+        (which, with chunk_size=1, also teacher-forces prompts). Each
+        phase is a span (DESIGN.md §12), and none adds a device sync: the
+        only host waits are ``serve.wait``'s pull of the sampled tokens
+        and the guard's finiteness pull."""
+        m = self.metrics
+        with m.span("serve.inputs"):
+            if prefilling:
+                toks, nv = self._chunk_inputs(active)
+                args = (self.params, self.state, jnp.asarray(toks),
+                        jnp.asarray(self.lengths), jnp.asarray(nv))
+            else:
+                args = (self.params, self.state,
+                        jnp.asarray(self.cur_tok), jnp.asarray(self.lengths))
+            if self.paged:
+                args += (self._block_tables(),)
+        kind = "prefill" if prefilling else "decode"
+        with m.span("serve.dispatch." + kind, active=len(active)):
+            logits, self.state = (self._prefill if prefilling
+                                  else self._decode)(*args)
+        with m.span("serve.sample"):
+            logits = self._chaos_logits(active, logits)
+            nxt = sample_tokens(self._sample_keys(), logits,
+                                temperature=self.temperature)
+        with m.span("serve.wait"):
+            nxt = np.asarray(nxt)
         self._c_ticks.inc()
-        self._c_prefill_steps.inc()
-        self._price_prefill(active, nv)
+        (self._c_prefill_steps if prefilling else self._c_decode_steps).inc()
         # sentinel before bookkeeping: a quarantined slot contributes no
         # length/token updates, so survivors see the same schedule a
         # fault-free run would (minus the freed capacity)
-        active = self._guard_nonfinite(active, logits, nxt)
+        with m.span("serve.guard"):
+            active = self._guard_nonfinite(active, logits, nxt)
+        with m.span("serve.bookkeep"):
+            if prefilling:
+                self._advance_prefill(active, nv, nxt)
+            else:
+                self._advance_decode(active, nxt)
+            if self.prefix_cache:
+                # index pages completed by this tick's writes (finished
+                # slots already registered theirs in _finish_or_continue)
+                self._register_active_pages()
+            self._track_memory(
+                [s for s in range(self.slots) if self.requests[s] is not None])
+
+    def _advance_prefill(self, active, nv, nxt):
         for s in active:
             req = self.requests[s]
             take = int(nv[s])
@@ -1087,21 +1094,7 @@ class ServeEngine:
                     continue                    # still mid-prompt: no sample
             self._finish_or_continue(s, int(nxt[s]))
 
-    def _decode_tick(self, active):
-        """Legacy single-token step; with chunk_size=1 it also teacher-forces
-        prompts (the pre-chunked-prefill behavior)."""
-        args = (self.params, self.state,
-                jnp.asarray(self.cur_tok), jnp.asarray(self.lengths))
-        if self.paged:
-            args += (self._block_tables(),)
-        logits, self.state = self._decode(*args)
-        logits = self._chaos_logits(active, logits)
-        nxt = np.asarray(sample_tokens(self._sample_keys(), logits,
-                                       temperature=self.temperature))
-        self._c_ticks.inc()
-        self._c_decode_steps.inc()
-        self._price_decode(active)
-        active = self._guard_nonfinite(active, logits, nxt)
+    def _advance_decode(self, active, nxt):
         for s in active:
             req = self.requests[s]
             if self.lengths[s] < len(req.prefill_toks):
@@ -1120,54 +1113,6 @@ class ServeEngine:
             else:
                 self._finish_or_continue(s, int(nxt[s]))
 
-    # -- executed-cost ledger (DESIGN.md §12) --------------------------------
-    def _price_prefill(self, active, nv):
-        """Ledger entry for one chunked-prefill step: each slot's chunk
-        priced at its actual (resident ctx, chunk) through the analytic
-        helpers, x attention layers. Called pre-length-increment, so
-        ``lengths[s]`` is the history the chunk attended over."""
-        ex = self._exec["prefill"]
-        g = self._geom
-        layout = "paged" if self.paged else "contiguous"
-        bytes_ = 0.0
-        flops = 0
-        kv = 0
-        for s in active:
-            chunk = int(nv[s])
-            ctx = int(self.lengths[s])
-            bytes_ += analytic_bytes_per_chunk_token(
-                layout, self.kv_dtype, ex["path"], Hkv=g["Hkv"], D=g["D"],
-                Dv=g["Dv"], ctx=ctx, chunk=chunk,
-                page_size=self.page_size or 1) * chunk
-            flops += analytic_attention_flops(
-                chunk, ctx + chunk, heads=g["heads"], d_qk=g["d_qk"],
-                d_v=g["d_v"])
-            kv += ctx + chunk
-        ex["calls"].inc(len(active))
-        ex["steps"].inc()
-        ex["tokens"].inc(kv)
-        ex["bytes"].inc(int(bytes_) * g["layers"])
-        ex["flops"].inc(flops * g["layers"])
-
-    def _price_decode(self, active):
-        """Ledger entry for one decode tick: every active slot reads its
-        resident history + the row written this tick."""
-        ex = self._exec["decode"]
-        g = self._geom
-        kv = 0
-        flops = 0
-        for s in active:
-            ctx = int(self.lengths[s]) + 1
-            kv += ctx
-            flops += analytic_attention_flops(
-                1, ctx, heads=g["heads"], d_qk=g["d_qk"], d_v=g["d_v"])
-        ex["calls"].inc(len(active))
-        ex["steps"].inc()
-        ex["tokens"].inc(kv)
-        ex["bytes"].inc(int(self._decode_bytes_per_ctx_token * kv)
-                        * g["layers"])
-        ex["flops"].inc(flops * g["layers"])
-
     def _track_memory(self, active):
         self._g_peak_active.set_max(
             int(sum(self.lengths[s] for s in active)))
@@ -1176,54 +1121,51 @@ class ServeEngine:
         self._g_peak_kv.set_max(int(used))
 
     def tick(self):
-        """Advance the engine by one step (prefill or decode)."""
+        """Advance the engine by one step (prefill or decode). The whole
+        call is one ``serve.tick`` span; ``active`` counts the slots held
+        when it starts."""
+        with self.metrics.span("serve.tick", step=self.ticks + 1,
+                               active=self.slots - self.requests.count(None)):
+            return self._tick()
+
+    def _tick(self):
+        m = self.metrics
         self._now = time.perf_counter()
-        # deadline sweep first: an expired request must not be admitted,
-        # reserved for, or stepped this tick
-        self._expire_deadlines()
-        self._admit()
-        self._g_queue.set(len(self.queue))
+        with m.span("serve.admit"):
+            # deadline sweep first: an expired request must not be
+            # admitted, reserved for, or stepped this tick
+            self._expire_deadlines()
+            self._admit()
+            self._g_queue.set(len(self.queue))
         active = [s for s in range(self.slots) if self.requests[s] is not None]
         if not active:
             return False
         if self.paged:
-            # forced-preemption chaos (§13): preemption is stream-preserving
-            # by the §7 recompute argument, so an injected storm must leave
-            # every temp-0 token stream bit-identical — only slower
-            for s in active:
-                if (self.requests[s] is not None and fault_point(
-                        "preempt", slot=s, rid=self.requests[s].rid)):
-                    self._preempt(s)
-            active = [s for s in active if self.requests[s] is not None]
-            active = self._reserve(active)
+            with m.span("serve.reserve"):
+                # forced-preemption chaos (§13): preemption is
+                # stream-preserving by the §7 recompute argument, so an
+                # injected storm must leave every temp-0 token stream
+                # bit-identical — only slower
+                for s in active:
+                    if (self.requests[s] is not None and fault_point(
+                            "preempt", slot=s, rid=self.requests[s].rid)):
+                        self._preempt(s)
+                active = [s for s in active if self.requests[s] is not None]
+                active = self._reserve(active)
+                # kv-corruption chaos after reservation, so the poisoned
+                # physical block id is the one this tick actually attends
+                # over
+                for s in active:
+                    if fault_point("kv_corrupt", slot=s,
+                                   rid=self.requests[s].rid):
+                        self._corrupt_kv(s)
             if not active:
                 return bool(self.queue)
-            # kv-corruption chaos after reservation, so the poisoned
-            # physical block id is the one this tick actually attends over
-            for s in active:
-                if fault_point("kv_corrupt", slot=s,
-                               rid=self.requests[s].rid):
-                    self._corrupt_kv(s)
         prefilling = self.chunk_size > 1 and any(
             self.requests[s].pos < len(self.requests[s].prefill_toks)
             for s in active
         )
-        if self.metrics.trace:
-            name = "prefill_step" if prefilling else "decode_step"
-            with self.metrics.span(name, step=self.ticks + 1,
-                                   active=len(active)):
-                (self._prefill_tick if prefilling
-                 else self._decode_tick)(active)
-        elif prefilling:
-            self._prefill_tick(active)
-        else:
-            self._decode_tick(active)
-        if self.prefix_cache:
-            # index pages completed by this tick's writes (finished slots
-            # already registered theirs in _finish_or_continue)
-            self._register_active_pages()
-        self._track_memory(
-            [s for s in range(self.slots) if self.requests[s] is not None])
+        self._step(active, prefilling)
         return True
 
     def run(self, max_steps: int | None = None):
@@ -1246,36 +1188,17 @@ class ServeEngine:
         return save_snapshot(self, path)
 
     # -- observability surfaces (DESIGN.md §12) ------------------------------
-    def attention_ledger(self) -> dict:
-        """Per-kind executed-cost rows: what the engine's steps were
-        *designed* to move and compute at their actual lengths — the live
-        fused-vs-gather byte ledger."""
-        return {
-            kind: {
-                "impl": ex["impl"],
-                "path": ex["path"],
-                "calls": ex["calls"].value,
-                "steps": ex["steps"].value,
-                "kv_tokens": ex["tokens"].value,
-                "analytic_bytes": ex["bytes"].value,
-                "analytic_flops": ex["flops"].value,
-            }
-            for kind, ex in self._exec.items()
-        }
-
     def metrics_snapshot(self) -> dict:
         """Everything observable about this engine as one JSON-able dict:
         the registry dump (counters/gauges/histograms — the per-spec
-        ``attention_dispatch_*`` and ``attention_exec_*`` families
-        included), TTFT/TPOT percentile conveniences (engine steps — the
-        scheduling-level latency signal), the executed-cost attention
-        ledger, and ``memory_stats()``."""
+        ``attention_dispatch_total`` family included), TTFT/TPOT
+        percentile conveniences (engine steps — the scheduling-level
+        latency signal), and ``memory_stats()``."""
         snap = self.metrics.snapshot()
         snap["ttft_steps_p50"] = self._h_ttft_steps.quantile(0.50)
         snap["ttft_steps_p99"] = self._h_ttft_steps.quantile(0.99)
         snap["tpot_steps_p50"] = self._h_tpot_steps.quantile(0.50)
         snap["tpot_steps_p99"] = self._h_tpot_steps.quantile(0.99)
-        snap["attention"] = self.attention_ledger()
         snap["memory"] = self.memory_stats()
         # terminal-state accounting (§13): every finished request counted
         # under exactly one reason; quarantines called out separately

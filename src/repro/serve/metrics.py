@@ -27,27 +27,27 @@ first-class observable facts of the serving stack:
     device syncs are ever issued for observability. ``chrome_trace()``
     exports the timeline as Chrome-trace/Perfetto JSON (eventful runs
     load directly in ``ui.perfetto.dev``).
+  * profiler spans — ``span()`` is the one span API, and it also enters
+    the registry's ``annotate(name, **args)`` context manager whether or
+    not ``trace`` is on. ``ServeEngine`` supplies
+    ``jax.profiler.TraceAnnotation``, so each ``serve.<phase>`` span lies
+    on the device trace's clock and a profiler session can name every
+    idle gap of the chip by the host work above it. With no profiler
+    session an annotation costs about a microsecond.
 
 Overhead contract (§12): with tracing **off** (the default) the hot path
-pays integer counter increments and one ``None`` check per record site —
-no span dicts, no per-token allocation, no timestamps beyond the ones the
-engine already takes, and no device synchronization. Counters and
-histograms stay live either way, so ``metrics_snapshot()`` is always
-well-formed.
+pays integer counter increments, one ``None`` check per record site and
+the profiler annotations of ``span()`` — no span dicts, no per-token
+allocation, no timestamps beyond the ones the engine already takes, and
+no device synchronization. Counters and histograms stay live either way,
+so ``metrics_snapshot()`` is always well-formed.
 
-Kernel-level cost accounting has two layers (both keyed by the resolved
-``AttentionSpec``):
-
-  * **dispatch counters** — ``install_dispatch_counters(registry)`` hooks
-    ``repro.kernels.registry`` so every ``dispatch_*`` call increments
-    ``attention_dispatch_total{kind,impl,...}`` and adds the call's
-    shape-level analytic HBM bytes/FLOPs (``repro.kernels.costs``).
-    Eager callers (tests, microbenches) count 1:1; under ``jax.jit`` a
-    dispatch runs at *trace* time, so these count compilations there.
-  * **executed-cost ledger** — ``ServeEngine`` prices every engine step
-    it actually runs (host-side lengths x the same analytic helpers)
-    into ``attention_exec_*`` counters: the live fused-vs-gather byte
-    ledger of DESIGN.md §12.
+``install_dispatch_counters(registry)`` hooks ``repro.kernels.registry``
+so every ``dispatch_*`` call increments ``attention_dispatch_total``
+keyed by the resolved ``AttentionSpec`` (kind, impl, variant, KV dtype,
+layout). Eager callers (tests, microbenches) count 1:1; under
+``jax.jit`` a dispatch runs at *trace* time, so these count compilations
+there.
 
 This module imports nothing but the standard library.
 """
@@ -177,15 +177,18 @@ _NS_PER_US = 1000.0
 
 
 class _Span:
-    """Context manager emitting one complete ("X") trace event."""
+    """Context manager emitting one complete ("X") trace event, inside
+    the profiler annotation ``ann`` when there is one."""
 
-    __slots__ = ("reg", "name", "pid", "tid", "args", "t0")
+    __slots__ = ("reg", "name", "pid", "tid", "args", "ann", "t0")
 
-    def __init__(self, reg, name, pid, tid, args):
+    def __init__(self, reg, name, pid, tid, args, ann):
         self.reg, self.name = reg, name
-        self.pid, self.tid, self.args = pid, tid, args
+        self.pid, self.tid, self.args, self.ann = pid, tid, args, ann
 
     def __enter__(self):
+        if self.ann is not None:
+            self.ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
@@ -196,6 +199,8 @@ class _Span:
             "ts": self.t0 / _NS_PER_US, "dur": (t1 - self.t0) / _NS_PER_US,
             "args": self.args,
         })
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         return False
 
 
@@ -228,10 +233,13 @@ class MetricsRegistry:
     lookup on hot paths. ``trace`` gates span/event recording only —
     counters and histograms are always live (they are the cheap part and
     ``metrics_snapshot()`` must stay well-formed with tracing off).
+    ``annotate(name, **args)``, when set, returns the context manager
+    every ``span()`` enters on the profiler's clock, traced or not.
     """
 
-    def __init__(self, *, trace: bool = False):
+    def __init__(self, *, trace: bool = False, annotate=None):
         self.trace = bool(trace)
+        self.annotate = annotate
         self._counters: dict = {}
         self._gauges: dict = {}
         self._histograms: dict = {}
@@ -270,11 +278,16 @@ class MetricsRegistry:
 
     def span(self, name: str, *, pid: int = PID_ENGINE, tid: int = 0,
              **args):
-        """``with reg.span("decode_step", active=3): ...`` — a complete
-        X event when tracing, the shared no-op otherwise."""
-        if not self.trace:
+        """``with reg.span("serve.tick", active=3): ...`` — the
+        ``annotate`` context manager, also recording a complete X event
+        when tracing; the shared no-op when neither is on."""
+        ann = self.annotate
+        if self.trace:
+            return _Span(self, name, pid, tid, args,
+                         None if ann is None else ann(name, **args))
+        if ann is None:
             return _NULL_SPAN
-        return _Span(self, name, pid, tid, args)
+        return ann(name, **args)
 
     def begin(self, name: str, *, pid: int = PID_ENGINE, tid: int = 0,
               **args):
@@ -443,38 +456,15 @@ def make_dispatch_sink(registry: MetricsRegistry):
 
     The sink runs at Python dispatch time — 1:1 with attention calls for
     eager callers, once per jit trace for compiled callers (documented in
-    DESIGN.md §12; the engine's executed-cost ledger covers per-step
-    attribution). Costs are **shape-level**: priced at the operand
-    capacity the call was traced with, via ``repro.kernels.costs``.
+    DESIGN.md §12).
     """
-    from repro.kernels import costs
 
     def sink(kind: str, spec, *, batch: int, heads: int, heads_kv: int,
              d_qk: int, d_v: int, kv_tokens: int, q_tokens: int,
              page_size: int = 0):
         layout = "paged" if kind.startswith("paged") else "contiguous"
-        labels = _spec_labels(kind, spec, layout, page_size)
-        path = costs.impl_path(labels["impl"])
-        registry.counter("attention_dispatch_total", **labels).inc()
-        if kind in ("decode", "paged_decode"):
-            per_tok = costs.analytic_bytes_per_ctx_token(
-                layout, spec.kv_dtype, path, Hkv=heads_kv, D=d_qk, Dv=d_v,
-                page_size=page_size or 1)
-            bytes_ = per_tok * kv_tokens * batch
-        else:
-            per_tok = costs.analytic_bytes_per_chunk_token(
-                layout, spec.kv_dtype, path, Hkv=heads_kv, D=d_qk, Dv=d_v,
-                ctx=kv_tokens, chunk=max(1, q_tokens),
-                page_size=page_size or 1)
-            bytes_ = per_tok * max(1, q_tokens) * batch
-        flops = costs.analytic_attention_flops(
-            max(1, q_tokens), kv_tokens + (q_tokens if "prefill" in kind
-                                           or kind == "full" else 0),
-            heads=heads, d_qk=d_qk, d_v=d_v) * batch
-        registry.counter("attention_dispatch_analytic_bytes",
-                         **labels).inc(int(bytes_))
-        registry.counter("attention_dispatch_analytic_flops",
-                         **labels).inc(int(flops))
+        registry.counter("attention_dispatch_total",
+                         **_spec_labels(kind, spec, layout, page_size)).inc()
 
     return sink
 
