@@ -220,8 +220,9 @@ def fused_paged_prefill_attention_pallas(
     """Fused paged prefill: the kernel's index maps resolve physical blocks
     from the block table per grid step (scalar prefetch), so the chunk
     attends to the paged history straight out of the pool — no gathered
-    copy (DESIGN.md §10). History tiles are whole pages; windows mask
-    in-kernel and whole pages below the window floor are skipped."""
+    copy (DESIGN.md §10). The history is walked in 128-token blocks of
+    whole pages, up to each slot's length; windows mask in-kernel and
+    whole blocks below the window floor are skipped."""
     B, H, C, D = q.shape
     pool_tokens, Hkv, _ = k_pool.shape
     Dv = v_pool.shape[-1]

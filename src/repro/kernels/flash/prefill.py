@@ -3,16 +3,25 @@ against [KV cache ++ chunk] without ever materializing the concatenation
 (DESIGN.md §10).
 
 This is the prefill twin of the flash-decode kernels (``kernels/decode``):
-the same shared online-softmax tile step (``flash/tile.py``), extended to a
-Tq × Tk grid over a *two-segment* KV axis. Grid = (B * H, q_blocks,
-cache_blocks + chunk_blocks); each program owns one query head's block_q
-chunk rows. KV grid steps 0..nkc-1 walk the resident cache (per-slot
-buffers here; the physical pool via block tables in the paged kernel),
-steps nkc.. walk the chunk's own fresh KV. Both segments are separate
-operands whose index maps *clamp* outside their own segment — a clamped
-map repeats the previous block index, so the pipeline never refetches it —
-and ``pl.when`` picks exactly one segment body per step. No gathered,
-concatenated, or dequantized copy of the history ever exists in HBM.
+the same shared online-softmax step (``flash/tile.py``), extended to a
+Tq × Tk grid over a *two-segment* KV axis: the resident history first,
+then the chunk's own fresh KV. Both segments are separate operands whose
+index maps *clamp* outside their own segment — a clamped map repeats the
+previous block index, so the pipeline never refetches it — and ``pl.when``
+picks exactly one segment body per step. No gathered, concatenated, or
+dequantized copy of the history ever exists in HBM.
+
+* contiguous kernel: grid (B * H, q_blocks, cache_blocks + chunk_blocks),
+  one query head's block_q chunk rows a program, one block_k tile a step.
+* paged kernel: a work list for a grid, one step per 128-token block
+  that has work — each slot's history blocks below its length, then its
+  chunk blocks below n_valid, an empty slot one step — all query heads of
+  a slot a program. A block is ``block_pages`` pages, each its own
+  operand whose index map reads the block table. A step stages its block
+  head-major in f32 scratch beside its mask; one loop over the query heads
+  serves both segments. Inside a block ExpMul keeps its page-sized key
+  tiles (``online_softmax_block``): the benchmark's reference walks page
+  tiles, and ExpMul's blocked result depends on the tiling.
 
 Masking is computed in-kernel from two per-sequence scalars (cache length
 and chunk validity count, a scalar-prefetch SMEM operand) instead of
@@ -41,13 +50,11 @@ still-quantized value tiles. Contiguous scale rows enter as
 
 The paged kernel takes the block table as a scalar-prefetch operand
 (``PrefetchScalarGridSpec``); index maps resolve ``block_table[b, page]``
-before each tile DMA, sentinel entries (= pool_blocks) are clamped into
-range and only ever cover positions >= length, which the mask hides. Pages
-entirely below a local window's floor are skipped outright. Its grid is
-(B, q_blocks, pages + chunk_pages): each DMA brings one page with all its
-KV heads (trailing ``(Hkv, D)`` dims whole, as TPU tiling requires; the
-chunk is passed token-major like the pool) and the kernel loops over the
-query heads statically.
+before each page DMA, sentinel entries (= pool_blocks) are clamped into
+range and only ever cover positions >= length, which the mask hides. Blocks
+entirely below a local window's floor are skipped. Each DMA brings one page
+with all its KV heads (trailing ``(Hkv, D)`` dims whole, as TPU tiling
+requires; the chunk is passed token-major like the pool).
 
 On CPU the kernels run in Pallas interpret mode (the wrappers in
 ``ops.py`` flip the flag automatically) — same math, no TPU lowering.
@@ -66,8 +73,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash.tile import (
     LANES as _LANES,
+    finalize_block,
     finalize_tiles,
     init_tiles,
+    online_softmax_block,
     online_softmax_tile,
 )
 
@@ -264,81 +273,171 @@ def prefill_fwd_pallas(
         interpret=interpret,
     )(meta, *args)
 
+# ---------------------------------------------------------------------------
+# Paged caches: a work list of 128-token blocks, each slot's only up to its
+# length, each page of a block brought by its own operand
+# ---------------------------------------------------------------------------
+def block_pages(page_size):
+    """Pages per block of the paged prefill kernel: 128 tokens' worth, so a
+    block fills the lanes; one page where the page does not divide 128."""
+    return _LANES // page_size if _LANES % page_size == 0 else 1
 
-# ---------------------------------------------------------------------------
-# Paged caches: in-kernel block-table indexing (scalar-prefetch index maps)
-# ---------------------------------------------------------------------------
-def _paged_prefill_kernel(bt_ref, meta_ref, q_ref, kc_ref, vc_ref, kn_ref,
-                          vn_ref, *refs, scale, variant, window, page_size,
-                          block_q, nkc, nkn, num_kv_heads, quant):
+
+def _paged_prefill_kernel(meta_ref, bt_ref, w_slot, w_qblk, w_kv, w_edge,
+                          w_hist, w_chunk, q_ref, *refs, scale, variant, window,
+                          page_size, pages, nkc, nkn, max_blocks, block_q,
+                          num_kv_heads, quant):
+    k_pages, v_pages = refs[:pages], refs[pages:2 * pages]
+    kn_ref, vn_ref = refs[2 * pages:2 * pages + 2]
+    refs = refs[2 * pages + 2:]
     if quant:
-        (ksc_ref, vsc_ref, ksn_ref, vsn_ref,
-         o_ref, m_scr, l_scr, acc_scr) = refs
+        ks_pages, vs_pages = refs[:pages], refs[pages:2 * pages]
+        refs = refs[2 * pages:]
+        ksn_ref, vsn_ref, o_ref, m_scr, l_scr, acc_scr, *blk = refs
+        q_t, k_blk, v_blk, mask_blk, ks_blk, vs_blk = blk
     else:
-        o_ref, m_scr, l_scr, acc_scr = refs
-        ksc_ref = vsc_ref = ksn_ref = vsn_ref = None
-    del bt_ref  # consumed by the index maps; the body never reads it
-    b = pl.program_id(0)
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+        o_ref, m_scr, l_scr, acc_scr, q_t, k_blk, v_blk, mask_blk = refs
+    del bt_ref, w_hist, w_chunk  # consumed by the index maps
+    t = pl.program_id(0)
+    b = w_slot[t]
+    ki = w_kv[t]
     length = meta_ref[b, 0]
     n_valid = meta_ref[b, 1]
     num_q_heads = q_ref.shape[1]
     group = num_q_heads // num_kv_heads
+    width = pages * page_size          # one block, tokens
 
-    @pl.when(ki == 0)
+    empty = ki == nkc + nkn            # a row block with nothing to read
+
+    @pl.when((w_edge[t] % 2 == 1) & ~empty)   # a row block's first step
     def _init():
         init_tiles(m_scr, l_scr, acc_scr)
+        for h in range(num_q_heads):
+            q_t[h] = q_ref[0, h].astype(jnp.float32).T
 
-    r0 = qi * block_q
-    iota_r = jax.lax.broadcasted_iota(jnp.int32, (block_q, page_size), 0)
-    iota_c = jax.lax.broadcasted_iota(jnp.int32, (block_q, page_size), 1)
+    r0 = w_qblk[t] * block_q
+    # the block step is transposed: keys along sublanes, query rows along
+    # lanes, so ExpMul's per-page maxima are reductions over sublanes
+    iota_r = jax.lax.broadcasted_iota(jnp.int32, (width, block_q), 1)
+    iota_c = jax.lax.broadcasted_iota(jnp.int32, (width, block_q), 0)
 
-    def heads(k_ref, v_ref, ks_ref, vs_ref, mask):
-        """One page (all KV heads) through the tile step of every q head."""
-        if quant:  # (page, Hkv) scale blocks -> one lane row per KV head
-            k_scales = ks_ref[0].T
-            v_scales = vs_ref[0].T
+    def concat(tiles):
+        return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, 0)
+
+    def stage(k_tiles, v_tiles, scale_rows, mask):
+        """Lay one block out head-major in f32 scratch beside its mask, so
+        a single head loop below serves both segments. ``k_tiles(g)`` gives
+        KV head g's pieces of the block, in key order."""
         for g in range(num_kv_heads):
-            k = k_ref[0, :, g, :].astype(jnp.float32)
-            v = v_ref[0, :, g, :].astype(jnp.float32)
-            for h in range(g * group, (g + 1) * group):
-                online_softmax_tile(
-                    q_ref[0, h].astype(jnp.float32), k, v,
-                    k_scales[g:g + 1] if quant else None,
-                    v_scales[g:g + 1] if quant else None,
-                    mask, m_scr.at[h], l_scr.at[h], acc_scr.at[h],
-                    scale=scale, variant=variant)
+            k_blk[g] = concat([x.astype(jnp.float32) for x in k_tiles(g)])
+            v_blk[g] = concat([x.astype(jnp.float32)
+                               for x in v_tiles(g)]).T
+            if quant:  # (block, Hkv) scale rows -> a (block, 1) column
+                for sc, sc_blk in zip(scale_rows, (ks_blk, vs_blk)):
+                    sc_blk[g] = sc[:, g:g + 1]
+        mask_blk[...] = mask.astype(jnp.int32)
 
-    # -- paged history: kv steps 0..nkc-1, absolute positions ---------------
-    c0 = ki * page_size
+    # -- paged history: kv steps 0..nkc-1, one block of pages each ---------
+    c0 = ki * width
     run_c = (ki < nkc) & (c0 < length)
-    if window is not None:
-        # pages entirely below the window floor of the lowest row skip
-        run_c = run_c & (c0 + page_size > length + r0 - window)
 
     @pl.when(run_c)
     def _cache():
-        heads(kc_ref, vc_ref, ksc_ref, vsc_ref,
-              _cache_tile_mask(length, nkc * page_size, c0, r0, iota_r,
-                               iota_c, window=window, rolling=False))
+        stage(lambda g: [r[0, :, g, :] for r in k_pages],
+              lambda g: [r[0, :, g, :] for r in v_pages],
+              (concat([r[0] for r in ks_pages]),
+               concat([r[0] for r in vs_pages])) if quant else None,
+              _cache_tile_mask(length, max_blocks * page_size, c0, r0,
+                               iota_r, iota_c, window=window, rolling=False))
 
-    # -- chunk segment ------------------------------------------------------
-    j0 = (ki - nkc) * page_size
+    # -- chunk segment: kv steps nkc.., one block of chunk tokens each ------
+    j0 = (ki - nkc) * width
     run_n = (ki >= nkc) & (j0 < n_valid) & (j0 < r0 + block_q)
-    if window is not None:
-        run_n = run_n & (j0 + page_size > r0 - window)
 
     @pl.when(run_n)
     def _chunk():
-        heads(kn_ref, vn_ref, ksn_ref, vsn_ref,
+        stage(lambda g: [kn_ref[0, :, g, :]],
+              lambda g: [vn_ref[0, :, g, :]],
+              (ksn_ref[0], vsn_ref[0]) if quant else None,
               _chunk_tile_mask(n_valid, j0, r0, iota_r, iota_c,
                                window=window))
 
-    @pl.when(ki == nkc + nkn - 1)
+    @pl.when(run_c | run_n)
+    def _attend():
+        mask = mask_blk[...] != 0
+
+        def head(h, carry):
+            g = h // group
+            online_softmax_block(
+                q_t[h], k_blk[g], v_blk[g],
+                ks_blk[g] if quant else None, vs_blk[g] if quant else None,
+                mask, m_scr.at[h], l_scr.at[h], acc_scr.at[h],
+                scale=scale, variant=variant, tile=page_size)
+            return carry
+
+        jax.lax.fori_loop(0, num_q_heads, head, 0)
+
+    @pl.when((w_edge[t] >= 2) & ~empty)       # a row block's last step
     def _fin():
         for h in range(num_q_heads):
-            finalize_tiles(o_ref.at[0, h], l_scr.at[h], acc_scr.at[h])
+            finalize_block(o_ref.at[0, h], l_scr.at[h], acc_scr.at[h])
+
+    @pl.when(empty)                    # fully masked rows read 0
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _work_list(meta, *, max_blocks, page_size, pages, nkc, nkn, nq,
+               block_q, window):
+    """The kernel's grid steps, one per block that has work (DESIGN.md
+    §10). Each (slot, q block) row block walks its history blocks below
+    ``length`` (from the window floor of its lowest row), then its chunk
+    blocks below ``n_valid``; a row block with neither takes one empty
+    step, so every output block is written.
+
+    Returns the number of steps and, per step: slot, q block, kv step
+    (< nkc: history block; nkc + j: chunk block j; nkc + nkn: none), an
+    edge code (1 first step of a row block, 2 last, 3 both), and the latest
+    step at or before it that read history (resp. the chunk), whose blocks
+    the index maps repeat, so a step fetches only what it reads. Running
+    sums and maxima are taken over the row blocks only; a step finds its
+    row block by one comparison against every row block's end and reads
+    the row block's values by one gather."""
+    B = meta.shape[0]
+    width = pages * page_size
+    length, n_valid = meta[:, 0], meta[:, 1]
+    r0 = jnp.arange(nq, dtype=jnp.int32) * block_q
+    hist = jnp.minimum(length, max_blocks * page_size)
+    hi_c = jnp.broadcast_to(-(-hist // width)[:, None], (B, nq))
+    hi_n = -(-jnp.minimum(n_valid[:, None], r0 + block_q) // width)
+    lo_c = lo_n = jnp.zeros((B, nq), jnp.int32)
+    if window is not None:  # blocks entirely below the window floor skip
+        lo_c = jnp.clip((length[:, None] + r0 - window + 1) // width, 0, hi_c)
+        lo_n = jnp.clip((r0 - window + 1) // width, 0, hi_n)
+    n_c, n_n, lo_c, lo_n = (x.ravel() for x in (hi_c - lo_c, hi_n - lo_n,
+                                                lo_c, lo_n))
+    n = jnp.maximum(n_c + n_n, 1)
+    ends = jnp.cumsum(n)
+    starts = ends - n
+    # each row block's last history (resp. chunk) step, or that of the
+    # nearest row block before it that has one
+    last_c = jax.lax.cummax(jnp.where(n_c > 0, starts + n_c - 1, 0))
+    last_n = jax.lax.cummax(jnp.where(n_n > 0, ends - 1, 0))
+    prev_n = jnp.concatenate([jnp.zeros((1,), jnp.int32), last_n[:-1]])
+    step = jnp.arange(B * nq * (nkc + nkn), dtype=jnp.int32)  # most steps
+    row = jnp.minimum(jnp.sum(ends[None, :] <= step[:, None], axis=1),
+                      B * nq - 1)
+    start, n_c, n_n, lo_c, lo_n, n, last_c, prev_n = jnp.stack(
+        [starts, n_c, n_n, lo_c, lo_n, n, last_c, prev_n], axis=1)[row].T
+    local = step - start
+    hist_step = local < n_c
+    kv = jnp.where(hist_step, lo_c + local, nkc + lo_n + local - n_c)
+    kv = jnp.where(n_c + n_n == 0, nkc + nkn, kv)
+    chunk_step = ~hist_step & (n_n > 0)
+    edge = (local == 0) + 2 * (local == n - 1)
+    return (ends[-1], row // nq, row % nq, kv, edge.astype(jnp.int32),
+            jnp.where(hist_step, step, last_c),
+            jnp.where(chunk_step, step, prev_n))
 
 
 @functools.partial(
@@ -347,7 +446,7 @@ def _paged_prefill_kernel(bt_ref, meta_ref, q_ref, kc_ref, vc_ref, kn_ref,
                      "interpret"),
 )
 def paged_prefill_fwd_pallas(
-    bt,          # (B, max_blocks) int32 block tables (scalar prefetch)
+    bt,          # (B, max_blocks) int32 block tables
     meta,        # (B, 2) int32: [:, 0] length, [:, 1] n_valid (scalar pref.)
     q4,          # (B, H, C_padq, D)
     k4,          # (pool_blocks, page_size, Hkv, D)   pool values or codes
@@ -369,67 +468,82 @@ def paged_prefill_fwd_pallas(
     B, H, Cq, D = q4.shape
     nblk, _, Hkv, _ = k4.shape
     Dv = v4.shape[-1]
-    Ck = kn4.shape[1]
     _, MB = bt.shape
     nq = Cq // block_q
-    nkn = Ck // page_size
     quant = ks3 is not None
+    pages = block_pages(page_size)
+    width = pages * page_size
+    nkc = -(-MB // pages)
+    chunk = [kn4, vn4] + ([ksn3, vsn3] if quant else [])
+    pad = -kn4.shape[1] % width            # whole blocks of chunk tokens
+    if pad:
+        chunk = [jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+                 for x in chunk]
+    nkn = chunk[0].shape[1] // width
+    steps, *work = _work_list(meta, max_blocks=MB, page_size=page_size,
+                              pages=pages, nkc=nkc, nkn=nkn, nq=nq,
+                              block_q=block_q, window=window)
     kernel = functools.partial(
         _paged_prefill_kernel, scale=scale, variant=variant, window=window,
-        page_size=page_size, block_q=block_q, nkc=MB, nkn=nkn,
-        num_kv_heads=Hkv, quant=quant,
+        page_size=page_size, pages=pages, nkc=nkc, nkn=nkn, max_blocks=MB,
+        block_q=block_q, num_kv_heads=Hkv, quant=quant,
     )
 
-    # the block table is resolved here, per grid step, before the tile DMA:
-    # sentinel entries (= pool_blocks, unallocated) are clamped into range —
-    # they only ever cover positions >= length, which the kernel masks.
-    # Outside the cache segment the page index clamps to the last table
-    # entry (repeated block => no refetch).
-    def pool_map(b, qi, ki, bt, meta):
-        page = jnp.minimum(bt[b, jnp.minimum(ki, MB - 1)], nblk - 1)
-        return (page, 0, 0, 0)
+    # The index maps resolve the block table per step, from the latest step
+    # that read history: pages past the slot's last live page repeat it, so
+    # no table entry past ``length`` is read; sentinels (= pool_blocks)
+    # clamp into range and only ever cover positions >= length.
+    def page_spec(i, *block):
+        def index(t, meta, bt, slot, qblk, kv, edge, hist, chunk):
+            b, blk = slot[hist[t]], kv[hist[t]]
+            live = pl.cdiv(jnp.minimum(meta[b, 0], MB * page_size),
+                           page_size)
+            page = jnp.clip(blk * pages + i, 0, jnp.maximum(live - 1, 0))
+            return (jnp.minimum(bt[b, page], nblk - 1),) + (0,) * (
+                1 + len(block))
+        return pl.BlockSpec((1, page_size, *block), index)
 
-    def chunk_map(b, qi, ki, bt, meta):
-        return (b, jnp.clip(ki - MB, 0, nkn - 1), 0, 0)
+    def chunk_spec(*block):
+        def index(t, meta, bt, slot, qblk, kv, edge, hist, chunk):
+            return (slot[chunk[t]], jnp.clip(kv[chunk[t]] - nkc, 0, nkn - 1)
+                    ) + (0,) * len(block)
+        return pl.BlockSpec((1, width, *block), index)
 
-    def pool_scale_map(b, qi, ki, bt, meta):
-        return pool_map(b, qi, ki, bt, meta)[:3]
-
-    def chunk_scale_map(b, qi, ki, bt, meta):
-        return chunk_map(b, qi, ki, bt, meta)[:3]
+    def rows(t, meta, bt, slot, qblk, kv, edge, hist, chunk):
+        return (slot[t], 0, qblk[t], 0)
 
     in_specs = [
-        pl.BlockSpec((1, H, block_q, D),
-                     lambda b, qi, ki, bt, meta: (b, 0, qi, 0)),
-        pl.BlockSpec((1, page_size, Hkv, D), pool_map),
-        pl.BlockSpec((1, page_size, Hkv, Dv), pool_map),
-        pl.BlockSpec((1, page_size, Hkv, D), chunk_map),
-        pl.BlockSpec((1, page_size, Hkv, Dv), chunk_map),
+        pl.BlockSpec((1, H, block_q, D), rows),
+        *[page_spec(i, Hkv, D) for i in range(pages)],
+        *[page_spec(i, Hkv, Dv) for i in range(pages)],
+        chunk_spec(Hkv, D), chunk_spec(Hkv, Dv),
     ]
-    args = [bt, meta, q4, k4, v4, kn4, vn4]
+    args = [q4, *[k4] * pages, *[v4] * pages, *chunk[:2]]
+    scratch = [                                      # transposed state
+        pltpu.VMEM((H, 1, block_q), jnp.float32),
+        pltpu.VMEM((H, 1, block_q), jnp.float32),
+        pltpu.VMEM((H, Dv, block_q), jnp.float32),
+        pltpu.VMEM((H, D, block_q), jnp.float32),    # q, per row block
+        pltpu.VMEM((Hkv, width, D), jnp.float32),    # the staged block
+        pltpu.VMEM((Hkv, Dv, width), jnp.float32),
+        pltpu.VMEM((width, block_q), jnp.int32),     # and its mask
+    ]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, page_size, Hkv), pool_scale_map),
-            pl.BlockSpec((1, page_size, Hkv), pool_scale_map),
-            pl.BlockSpec((1, page_size, Hkv), chunk_scale_map),
-            pl.BlockSpec((1, page_size, Hkv), chunk_scale_map),
-        ]
-        args += [ks3, vs3, ksn3, vsn3]
+        in_specs += [*[page_spec(i, Hkv) for i in range(pages)],
+                     *[page_spec(i, Hkv) for i in range(pages)],
+                     chunk_spec(Hkv), chunk_spec(Hkv)]
+        args += [*[ks3] * pages, *[vs3] * pages, *chunk[2:]]
+        scratch += [pltpu.VMEM((Hkv, width, 1), jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, nq, MB + nkn),
+        num_scalar_prefetch=8,
+        grid=(steps,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, block_q, Dv),
-                               lambda b, qi, ki, bt, meta: (b, 0, qi, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, block_q, _LANES), jnp.float32),
-            pltpu.VMEM((H, block_q, _LANES), jnp.float32),
-            pltpu.VMEM((H, block_q, Dv), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, H, block_q, Dv), rows),
+        scratch_shapes=scratch,
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Cq, Dv), q4.dtype),
         interpret=interpret,
-    )(*args)
+    )(meta, bt, *work, *args)

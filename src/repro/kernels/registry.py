@@ -71,6 +71,8 @@ import dataclasses
 
 import jax
 
+from repro.kernels.flash.prefill import block_pages
+
 
 @dataclasses.dataclass(frozen=True)
 class AttentionSpec:
@@ -214,15 +216,17 @@ register_paged_prefill = _make_register(_PAGED_PREFILL_IMPLS, "paged prefill")
 register_paged_decode = _make_register(_PAGED_DECODE_IMPLS, "paged decode")
 
 
-def resolved_backends(spec: AttentionSpec, *, paged: bool = False) -> list[dict]:
+def resolved_backends(spec: AttentionSpec, *, paged: bool = False,
+                      page_size: int = 0) -> list[dict]:
     """What this spec actually runs, per dispatch table.
 
     Returns one dict per table: ``{"kind", "requested", "resolved",
     "fallback", "note"}`` where ``resolved`` differs from ``requested``
     when the registered entry is a declared fallback onto another
     implementation's math, and ``note`` carries the CPU interpret-mode
-    caveat for Pallas kernels. Serving engines log the non-trivial rows
-    once at startup (DESIGN.md §9).
+    caveat for Pallas kernels and, given the ``page_size``, the history
+    block the fused paged prefill walks (DESIGN.md §10). Serving engines
+    log the non-trivial rows once at startup (DESIGN.md §9).
     """
     _lookup(_ATTENTION_IMPLS, "ref", "full-sequence")  # force registration
     kinds = [
@@ -239,9 +243,15 @@ def resolved_backends(spec: AttentionSpec, *, paged: bool = False) -> list[dict]
     out = []
     for kind, name in kinds:
         resolved = _FALLBACK_NOTES.get((kind, name), name)
-        note = ""
+        notes = []
         if on_cpu and "pallas" in resolved:
-            note = "interpret mode (CPU has no Pallas TPU lowering)"
+            notes.append("interpret mode (CPU has no Pallas TPU lowering)")
+        if (kind == "paged prefill" and resolved in ("pallas", "pallas_q")
+                and page_size):
+            pages = block_pages(page_size)
+            notes.append(f"{pages} pages of {page_size} per block "
+                         f"({pages * page_size} tokens)")
+        note = "; ".join(notes)
         out.append({
             "kind": kind,
             "requested": name,

@@ -138,12 +138,14 @@ logger = logging.getLogger("repro.serve")
 _LOGGED_BACKENDS: set[str] = set()
 
 
-def _log_resolved_backends(cfg, paged: bool):
+def _log_resolved_backends(cfg, paged: bool, page_size: int):
     """One startup line per non-obvious backend resolution (DESIGN.md §9):
     declared fallbacks (a requested impl routing to another impl's math)
-    and the CPU interpret-mode caveat for Pallas kernels — so a config
-    knob can never silently mean something else."""
-    for row in resolved_backends(AttentionSpec.from_config(cfg), paged=paged):
+    and the registry's notes (the CPU interpret-mode caveat for Pallas
+    kernels, the history block the fused paged prefill walks) — so a
+    config knob can never silently mean something else."""
+    for row in resolved_backends(AttentionSpec.from_config(cfg), paged=paged,
+                                 page_size=page_size):
         if not (row["fallback"] or row["note"]):
             continue
         msg = f"attention {row['kind']}: requested {row['requested']!r}"
@@ -305,7 +307,8 @@ class ServeEngine:
             # "pallas" turns on the fused paged decode of DESIGN.md §9)
             cfg = cfg.replace(attention_impl=attention_impl)
         self.attention_impl = cfg.attention_impl
-        _log_resolved_backends(cfg, kv_layout == "paged")
+        ps = int(page_size or cfg.page_size)
+        _log_resolved_backends(cfg, kv_layout == "paged", ps)
         self.params = params
         self.cfg = cfg
         self.slots = slots
@@ -341,7 +344,6 @@ class ServeEngine:
         # scale pools for quantized dtypes) — the unit of every *_bytes stat
         self.token_bytes = kv_token_bytes(cfg, self.kv_dtype)
         if self.paged:
-            ps = int(page_size or cfg.page_size)
             max_blocks = blocks_for(max_len, ps)
             requested = int(pool_blocks or cfg.pool_blocks or 0)
             if requested:
