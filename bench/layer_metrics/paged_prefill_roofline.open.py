@@ -1,6 +1,7 @@
 """Kernels (kernels/flash/prefill.py, the fused paged prefill): the least
-time the chip needs for the kernel's work (bench/costs, per layer call)
-over the kernel's device time, across the traced prefill ticks."""
+time the chip needs for the kernel's work (the architecture's
+``attention_kernel_cost``, per layer call) over the kernel's device
+time, across the traced prefill ticks."""
 from bench import costs, trace_reduce
 
 KERNEL = "paged_prefill_fwd_pallas"
@@ -13,7 +14,7 @@ def read(run):
             continue
         calls = trace_reduce.ops_in(run.trace["ops"], a, b, KERNEL)
         spent += sum(d for _, _, d in calls) / 1e9
-        work = costs.attention_kernel_cost(run.sz, t["rows"],
-                                           costs.cache_dtype(run.cell.config))
+        work = run.cell.arch.attention_kernel_cost(
+            run.sz, t["rows"], costs.cache_dtype(run.cell.config))
         need += run.sz["layers"] * costs.roofline_seconds(*work, run.peak)
     return 100.0 * need / spent if spent else None

@@ -98,9 +98,9 @@ def build_engine(cell: spec.Cell, seed: int):
     """The ``ServeEngine`` for ``cell`` with weights from ``seed``."""
     from repro.serve.engine import ServeEngine
 
-    conf = cell.config
-    cfg = model.program_config(conf)
-    params = model.to_program(model.canonical_weights(seed, conf), conf)
+    conf, arch = cell.config, cell.arch
+    cfg = arch.program_config(conf)
+    params = arch.to_program(arch.canonical_weights(seed, conf), conf)
     model.check_program_layout(params, cfg)
     return ServeEngine(params, cfg, seed=0, **conf["engine"])
 
@@ -346,17 +346,16 @@ def pick_requests(tracks, seed: int) -> list:
 
 def served_gaps(cell: spec.Cell, seed: int, picked, control: bool):
     """Per served token of the ``picked`` requests, the reference's gap
-    (and the float8 control's, with ``control``), as two numpy arrays."""
+    (and the float8 control's, with ``control``), as two numpy arrays.
+    The reference is the file the configuration names."""
     import jax.numpy as jnp
 
-    from bench import reference
-
     conf = cell.config
-    sz = model.sizes(conf)
+    sz = cell.arch.sizes(conf)
     S = conf["engine"]["max_len"]
     rows = cell.traffic["output_tokens"]["max"]
     tile = conf["engine"]["page_size"]
-    w = model.canonical_weights(seed, conf)
+    w = cell.arch.canonical_weights(seed, conf)
     gaps, cgaps = [], []
     for t in picked:
         prompt, out = t.req.prompt, t.req.out
@@ -368,7 +367,7 @@ def served_gaps(cell: spec.Cell, seed: int, picked, control: bool):
         split[P:P + len(t.split)] = t.split
         served = np.zeros(rows, np.int32)
         served[:n] = out
-        g, c = reference.served_gaps(
+        g, c = cell.reference.served_gaps(
             w, jnp.asarray(toks), jnp.asarray(split), jnp.int32(P - 1),
             jnp.asarray(served), jnp.int32(n), sz=tuple(sorted(sz.items())),
             variant=conf["attention_variant"], tile=tile, rows=rows,
@@ -439,7 +438,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     compiles = CompileCounter()
     peak = costs.peaks(device["kind"]) if require_tpu else None
     conf, mix = cell.config, cell.traffic
-    sz = model.sizes(conf)
+    sz = cell.arch.sizes(conf)
     eng = build_engine(cell, seed)
     drv = Driver(eng, annotate=trace)
     warm_up(drv, sz["vocab"])
@@ -597,8 +596,9 @@ def tick_kinds_named(host, traced):
 
 class RunData:
     """What a per-layer metric's reader gets: the window's ticks and
-    requests as the benchmark saw them, the shapes, the chip's peaks, and
-    the reduced trace (``None`` without ``--trace 1``)."""
+    requests as the benchmark saw them, the cell (``cell.arch`` counts
+    its work) and its shapes, the chip's peaks, and the reduced trace
+    (``None`` without ``--trace 1``)."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)

@@ -27,7 +27,7 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import numpy as np  # noqa: E402
 
-from bench import model, run, spec, traffic  # noqa: E402
+from bench import run, spec, traffic  # noqa: E402
 
 
 def pct(values, q):
@@ -51,7 +51,7 @@ def main(argv=None) -> int:
         run.log(f"sweep: {e}")
         return 3
     run.use_compile_cache()
-    sz = model.sizes(cell.config)
+    sz = cell.arch.sizes(cell.config)
     eng = run.build_engine(cell, args.seed)
     drv = run.Driver(eng, annotate=False)
     run.warm_up(drv, sz["vocab"])

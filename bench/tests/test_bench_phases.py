@@ -14,7 +14,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import costs, model, phases, run, spec  # noqa: E402
+from bench import costs, phases, run, spec  # noqa: E402
 
 DATA = ROOT / "bench/testdata"
 SMALL = json.loads((DATA / "trace_small.json").read_text())
@@ -86,7 +86,7 @@ def _run_data(trace):
               "rows": [(128, 1, True), (301, 1, True)]}]
     tracks = [SimpleNamespace(req=SimpleNamespace(admit_time=1.25), due=1.0)]
     return run.RunData(
-        cell=cell, sz=model.sizes(cell.config),
+        cell=cell, sz=cell.arch.sizes(cell.config),
         peak=costs.peaks("TPU v5 lite"), slots=64, ticks=ticks,
         tracks=tracks, trace=trace,
         traced_ticks=run.pair_traced_ticks(ticks, trace, host_start=0.5))

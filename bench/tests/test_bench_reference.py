@@ -1,7 +1,8 @@
 """The benchmark's reference against the program, on the CPU in float32:
 the same weights give the same logits, so the reference computes the
-model the program serves (a witness for the adapter in bench/model.py,
-the rotary convention, the norms and, for ExpMul, the tiling)."""
+model the program serves (a witness for the adapter in
+bench/archs/qwen2.py, the rotary convention, the norms and, for ExpMul,
+the tiling)."""
 import json
 import sys
 from pathlib import Path
@@ -14,7 +15,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from bench import model, reference  # noqa: E402
+from bench import model, reference_core, spec  # noqa: E402
 
 
 def _tiny(variant):
@@ -31,11 +32,12 @@ def test_reference_logits_match_the_program_in_float32(variant, impl):
     from repro.models.api import forward
 
     conf = _tiny(variant)
-    sz = model.sizes(conf)
-    w = model.canonical_weights(2**32 + 7, conf)
-    params = model.to_program(w, conf)
+    arch, ref = spec.arch_module(conf), spec.reference_module(conf)
+    sz = arch.sizes(conf)
+    w = arch.canonical_weights(2**32 + 7, conf)
+    params = arch.to_program(w, conf)
     # 16-token key tiles in the program's flash kernel, as in a paged step
-    cfg = model.program_config(conf).replace(
+    cfg = arch.program_config(conf).replace(
         attention_impl=impl, attention_block_q=16, attention_block_k=16,
         attention_q_chunks=1)
     model.check_program_layout(params, cfg)
@@ -43,8 +45,8 @@ def test_reference_logits_match_the_program_in_float32(variant, impl):
     toks = jax.random.randint(jax.random.PRNGKey(1), (S,), 1, 512)
     with jax.default_matmul_precision("highest"):
         got = forward(params, {"tokens": toks[None]}, cfg)[0]
-    h = reference.hidden(w, toks, jnp.zeros(S, bool), sz, variant, 16, None)
-    want = reference.logits(w, h, None)
+    h = ref.hidden(w, toks, jnp.zeros(S, bool), sz, variant, 16, None)
+    want = ref.logits(w, h, None)
     err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
     assert err < 1e-4, err
 
@@ -57,8 +59,8 @@ def test_lone_token_tile_changes_expmul():
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
     q, k, v = (jax.random.normal(kk, (H, S, D)) for kk in (k1, k2, k3))
     split = jnp.asarray(np.arange(S) % 3 == 1)
-    a = reference._attn_expmul(q, k, v, 0.25, jnp.zeros(S, bool), 16)
-    b = reference._attn_expmul(q, k, v, 0.25, split, 16)
+    a = reference_core.attn_expmul(q, k, v, 0.25, jnp.zeros(S, bool), 16)
+    b = reference_core.attn_expmul(q, k, v, 0.25, split, 16)
     assert float(jnp.max(jnp.abs(a - b))) > 1e-3
     # rows whose own token starts a tile are unchanged by the split
     rows = np.arange(S)[(np.arange(S) % 16 == 0)]

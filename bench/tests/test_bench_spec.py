@@ -21,14 +21,22 @@ def test_every_committed_cell_resolves():
         assert cell.cell["limits"]
         for m in cell.per_layer:
             assert callable(cell.layer_reader(m["name"]))
+        # the architecture by model_type, the reference by its key
+        assert Path(cell.arch.__file__) == \
+            ROOT / "bench/archs" / f"{cell.config['model_type']}.py"
+        assert Path(cell.reference.__file__) == \
+            ROOT / cell.config["reference"]
+        assert callable(cell.reference.served_gaps)
         names = {m["name"] for m in cell.end_to_end}
         assert "setup_s" in names and len(names) >= 2
 
 
 def test_files_dropped_into_a_new_root_are_found_by_name(tmp_path):
     (tmp_path / "bench").mkdir()
-    for sub in ("configs", "traffic", "cells", "layer_metrics"):
+    for sub in ("configs", "traffic", "cells", "layer_metrics", "archs"):
         (tmp_path / "bench" / sub).mkdir()
+    shutil.copy(ROOT / "bench/archs/qwen2.py", tmp_path / "bench/archs")
+    shutil.copy(ROOT / "bench/reference.py", tmp_path / "bench")
     conf = json.loads((ROOT / "bench/configs/qwen2-0.5b.json").read_text())
     conf["name"] = "newcfg"
     (tmp_path / "bench/configs/newcfg.json").write_text(json.dumps(conf))
@@ -53,6 +61,8 @@ def test_files_dropped_into_a_new_root_are_found_by_name(tmp_path):
     assert cell.cell["rate_per_s"] == 3.0
     assert [m["name"] for m in cell.end_to_end] == ["setup_s"]
     assert cell.layer_reader("new_metric.x")(None) == 42.0
+    assert Path(cell.arch.__file__) == tmp_path / "bench/archs/qwen2.py"
+    assert Path(cell.reference.__file__) == tmp_path / "bench/reference.py"
 
 
 def test_unknown_cell_is_refused():
