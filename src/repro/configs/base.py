@@ -17,14 +17,35 @@ class MoEConfig:
     d_ff: int = 0                  # per-expert hidden size
     dense_residual: bool = False   # arctic: dense FFN in parallel with MoE
     dense_d_ff: int = 0            # hidden of the dense residual path
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # scatter/balanced only; dropless keeps all
     router_dtype: str = "float32"  # router softmax kept exact (DESIGN §4)
+    # "softmax": softmax over the top-k logits. "sigmoid": DeepSeek-V3's
+    # noaux_tc, sigmoid scores, top-k chosen on score + a per-expert
+    # correction bias, weights the unbiased scores of the chosen experts
+    # normalised over the k (DESIGN.md §14)
+    router: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    num_shared_experts: int = 0    # one SwiGLU of width num_shared * d_ff
+    # the contiguous range [start, stop) of routed experts this layer holds
+    # (one chip's share under expert parallelism); None holds all of them
+    held_experts: tuple[int, int] | None = None
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        return self.held_experts or (0, self.num_experts)
+
+    @property
+    def num_held(self) -> int:
+        start, stop = self.held_range
+        return stop - start
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """Multi-head latent attention (MiniCPM3 / DeepSeek-V2 family)."""
-    q_lora_rank: int = 768
+    """Multi-head latent attention (MiniCPM3 / DeepSeek-V2 family).
+    ``q_lora_rank=None``: queries are a plain projection (DeepSeek-V2-Lite,
+    Moonlight)."""
+    q_lora_rank: int | None = 768
     kv_lora_rank: int = 256
     qk_rope_dim: int = 32
     qk_nope_dim: int = 64
@@ -45,6 +66,7 @@ class ModelConfig:
     activation: str = "swiglu"     # swiglu | geglu | gelu
     qkv_bias: bool = False
     norm: str = "rmsnorm"
+    norm_eps: float = 1e-6
     tie_embeddings: bool = True
     scale_embeddings: bool = False  # gemma: embed * sqrt(d_model)
     rope_base: float = 10000.0
@@ -52,6 +74,10 @@ class ModelConfig:
 
     moe: MoEConfig | None = None
     mla: MLAConfig | None = None
+
+    # leading dense layers before the (MoE) stack: attention blocks whose
+    # FFN is a dense SwiGLU of width d_ff (DeepSeek's first_k_dense_replace)
+    first_dense_layers: int = 0
 
     # hybrid / ssm block patterns; unit repeats to fill num_layers
     block_pattern: tuple[str, ...] = ("attn",)   # attn|rglru|mlstm|slstm
@@ -125,7 +151,10 @@ def _attn_params(cfg: ModelConfig) -> int:
     if cfg.mla is not None:
         m = cfg.mla
         qk_head = m.qk_nope_dim + m.qk_rope_dim
-        p = d * m.q_lora_rank + m.q_lora_rank * cfg.num_heads * qk_head
+        if m.q_lora_rank is None:
+            p = d * cfg.num_heads * qk_head
+        else:
+            p = d * m.q_lora_rank + m.q_lora_rank * cfg.num_heads * qk_head
         p += d * (m.kv_lora_rank + m.qk_rope_dim)
         p += m.kv_lora_rank * cfg.num_heads * (m.qk_nope_dim + m.v_head_dim)
         p += cfg.num_heads * m.v_head_dim * d
@@ -142,15 +171,18 @@ def _ffn_params(cfg: ModelConfig, d_ff: int) -> int:
     return mult * cfg.d_model * d_ff
 
 
-def _block_params(cfg: ModelConfig, kind: str, active_only: bool) -> int:
+def _block_params(cfg: ModelConfig, kind: str, active_only: bool,
+                  dense: bool = False) -> int:
     d = cfg.d_model
     if kind == "attn":
         p = _attn_params(cfg)
-        if cfg.moe is not None:
-            k = cfg.moe.top_k if active_only else cfg.moe.num_experts
-            p += k * _ffn_params(cfg, cfg.moe.d_ff) + d * cfg.moe.num_experts
-            if cfg.moe.dense_residual:
-                p += _ffn_params(cfg, cfg.moe.dense_d_ff)
+        if cfg.moe is not None and not dense:
+            m = cfg.moe
+            k = m.top_k if active_only else m.num_experts
+            p += k * _ffn_params(cfg, m.d_ff) + d * m.num_experts
+            p += _ffn_params(cfg, m.num_shared_experts * m.d_ff)
+            if m.dense_residual:
+                p += _ffn_params(cfg, m.dense_d_ff)
         elif cfg.d_ff:
             p += _ffn_params(cfg, cfg.d_ff)
         return p + 2 * d
@@ -186,8 +218,9 @@ def _param_count(cfg: ModelConfig, active_only: bool = False) -> int:
         for _ in range(cfg.decoder_layers):
             total += _block_params(cfg, "attn", active_only) + _attn_params(cfg) + cfg.d_model
         return total
-    for kind in cfg.pattern_for():
-        total += _block_params(cfg, kind, active_only)
+    for i, kind in enumerate(cfg.pattern_for()):
+        total += _block_params(cfg, kind, active_only,
+                               dense=i < cfg.first_dense_layers)
     if cfg.frontend:
         total += cfg.frontend_dim * cfg.d_model
     return total

@@ -7,6 +7,7 @@ _ARCH_MODULES = {
     "llava-next-34b": "repro.configs.llava_next_34b",
     "gemma-7b": "repro.configs.gemma_7b",
     "minicpm3-4b": "repro.configs.minicpm3_4b",
+    "moonlight-16b-a3b": "repro.configs.moonlight_16b_a3b",
     "qwen1.5-0.5b": "repro.configs.qwen1_5_0_5b",
     "qwen2-0.5b": "repro.configs.qwen2_0_5b",
     "seamless-m4t-medium": "repro.configs.seamless_m4t_medium",

@@ -490,7 +490,7 @@ def _paged_prefill_gather_pallas(q, k_chunk, v_chunk, k_pool, v_pool, rows,
 @register_paged_prefill("pallas")
 def _paged_prefill_pallas(q, k_chunk, v_chunk, k_pool, v_pool, rows, *,
                           spec, scale, q_positions, chunk_valid, lengths,
-                          block_tables=None, page_size=0):
+                          block_tables=None, page_size=0, v_width=None):
     """Fused paged prefill (DESIGN.md §10): block-table indexing happens
     inside the kernel's index maps, so the chunk attends to the history
     straight out of the pool — no materialized gather copy. Windows mask
@@ -505,12 +505,12 @@ def _paged_prefill_pallas(q, k_chunk, v_chunk, k_pool, v_pool, rows, *,
     return fused_paged_prefill_attention_pallas(
         q, k_chunk, v_chunk, k_pool, v_pool, block_tables, lengths, n_valid,
         page_size=page_size, scale=scale, variant=spec.variant,
-        window=spec.window, block_q=spec.block_q)
+        window=spec.window, block_q=spec.block_q, v_width=v_width)
 
 
 @register_paged_decode("pallas")
 def _paged_decode_pallas(q, k_pool, v_pool, rows, lengths, *, spec, scale,
-                         block_tables=None, page_size=0):
+                         block_tables=None, page_size=0, v_width=None):
     """Fused paged flash-decode (DESIGN.md §9): block-table indexing happens
     inside the kernel's index maps, so the history is read straight out of
     the pool — no materialized gather copy. Windows mask in-kernel. Callers
@@ -521,7 +521,8 @@ def _paged_decode_pallas(q, k_pool, v_pool, rows, lengths, *, spec, scale,
                                            spec=spec, scale=scale)
     return fused_paged_decode_attention_pallas(
         q, k_pool, v_pool, block_tables, lengths, page_size=page_size,
-        scale=scale, variant=spec.variant, window=spec.window)
+        scale=scale, variant=spec.variant, window=spec.window,
+        v_width=v_width)
 
 
 @register_paged_decode("gather_pallas")

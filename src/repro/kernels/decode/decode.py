@@ -30,7 +30,10 @@ Three kernels share one online-softmax tile step (``_online_softmax_step``):
   positions at/after ``length`` so the length mask hides them. Local
   windows mask positions below ``length - window`` in-kernel (paged caches
   keep absolute positions; DESIGN.md §7), and whole pages outside
-  [length - window, length) are skipped.
+  [length - window, length) are skipped. MLA's absorbed latent (DESIGN.md
+  §14) is one shared KV head whose pool is ``(pool_blocks, page_size, Dk)``
+  and whose V is the first ``v_width`` columns of K's page: one DMA a page
+  serves both products.
 
 The ExpMul variant applies the paper's operator to the decode path, where
 the softmax/rescale work is the dominant VPU cost (there is no large matmul
@@ -158,8 +161,11 @@ def decode_fwd_pallas(
 # ---------------------------------------------------------------------------
 # Paged caches: in-kernel block-table indexing (scalar-prefetch index maps)
 # ---------------------------------------------------------------------------
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *refs, scale,
-                         variant, page_size, nk, quant, window, num_kv_heads):
+def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, *refs, scale,
+                         variant, page_size, nk, quant, window, num_kv_heads,
+                         v_width):
+    if v_width is None:
+        v_ref, *refs = refs
     if quant:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -189,10 +195,14 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *refs, scale,
             k_scales = ks_ref[0].T
             v_scales = vs_ref[0].T
         for h in range(num_kv_heads):
+            if v_width is None:
+                k = k_ref[0, :, h, :].astype(jnp.float32)
+                v = v_ref[0, :, h, :].astype(jnp.float32)
+            else:  # one shared latent head: V is K's first v_width columns
+                k = k_ref[0].astype(jnp.float32)
+                v = k[:, :v_width]
             _online_softmax_step(
-                q_ref[0, h].astype(jnp.float32),
-                k_ref[0, :, h, :].astype(jnp.float32),
-                v_ref[0, :, h, :].astype(jnp.float32),
+                q_ref[0, h].astype(jnp.float32), k, v,
                 k_scales[h:h + 1] if quant else None,
                 v_scales[h:h + 1] if quant else None,
                 mask, m_scr.at[h], l_scr.at[h], acc_scr.at[h],
@@ -206,7 +216,8 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *refs, scale,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("scale", "variant", "page_size", "window", "interpret"),
+    static_argnames=("scale", "variant", "page_size", "window", "v_width",
+                     "interpret"),
 )
 def paged_decode_fwd_pallas(
     bt,         # (B, max_blocks) int32 block tables (scalar prefetch)
@@ -222,16 +233,20 @@ def paged_decode_fwd_pallas(
     page_size,
     window,
     interpret,
+    v_width=None,
 ):
+    """``v4=None`` with ``v_width``: one shared latent head (MLA absorbed,
+    DESIGN.md §14), k4 (pool_blocks, page_size, D) holding V in its first
+    ``v_width`` columns, so each page is fetched once for both."""
     B, Hkv, group, D = q4.shape
     nblk = k4.shape[0]
-    Dv = v4.shape[-1]
+    Dv = v4.shape[-1] if v4 is not None else v_width
     _, MB = bt.shape
     quant = ks3 is not None
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, variant=variant,
         page_size=page_size, nk=MB, quant=quant, window=window,
-        num_kv_heads=Hkv,
+        num_kv_heads=Hkv, v_width=None if v4 is not None else v_width,
     )
 
     # The block table is resolved here, per grid step, before the tile DMA:
@@ -245,10 +260,14 @@ def paged_decode_fwd_pallas(
 
     in_specs = [
         pl.BlockSpec((1, Hkv, group, D), lambda b, ki, bt, ln: (b, 0, 0, 0)),
-        pl.BlockSpec((1, page_size, Hkv, D), page_map),
-        pl.BlockSpec((1, page_size, Hkv, Dv), page_map),
     ]
-    args = [bt, len1.astype(jnp.int32), q4, k4, v4]
+    args = [bt, len1.astype(jnp.int32), q4, k4]
+    if v4 is None:
+        in_specs.append(pl.BlockSpec((1, page_size, D), scale_map))
+    else:
+        in_specs += [pl.BlockSpec((1, page_size, Hkv, D), page_map),
+                     pl.BlockSpec((1, page_size, Hkv, Dv), page_map)]
+        args.append(v4)
     if quant:
         in_specs += [
             pl.BlockSpec((1, page_size, Hkv), scale_map),

@@ -116,7 +116,7 @@ def _paged_operands(q, pool_tokens, page_size, Hkv):
 def fused_paged_decode_attention_pallas(
     q: jax.Array,         # (B, H, D)
     k_pool: jax.Array,    # (pool_tokens, Hkv, D) flat physical pool
-    v_pool: jax.Array,    # (pool_tokens, Hkv, Dv)
+    v_pool: jax.Array | None,  # (pool_tokens, Hkv, Dv)
     block_tables: jax.Array,  # (B, max_blocks) int32, sentinel = pool_blocks
     lengths: jax.Array,   # (B,) valid entries incl. the current token
     *,
@@ -124,29 +124,34 @@ def fused_paged_decode_attention_pallas(
     scale: float | None = None,
     variant: str = "exact",
     window: int | None = None,
+    v_width: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused paged flash-decode: the kernel's index maps resolve physical
     blocks from the block table per grid step, so the paged history is read
     straight out of the pool — no materialized ``gather_rows`` copy
-    (DESIGN.md §9). Windows are masked in-kernel by absolute position."""
+    (DESIGN.md §9). Windows are masked in-kernel by absolute position.
+    ``v_pool=None``: the shared latent pool (pool_tokens, D) whose rows
+    hold V in their first ``v_width`` columns (DESIGN.md §14)."""
     B, H, D = q.shape
-    pool_tokens, Hkv, _ = k_pool.shape
-    Dv = v_pool.shape[-1]
+    latent = v_pool is None
+    pool_tokens = k_pool.shape[0]
+    Hkv = 1 if latent else k_pool.shape[1]
     interpret = _interpret_default(interpret)
     scale = float(1.0 / np.sqrt(D)) if scale is None else float(scale)
     q4, nblk = _paged_operands(q, pool_tokens, page_size, Hkv)
     o4 = paged_decode_fwd_pallas(
         block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q4,
-        k_pool.reshape(nblk, page_size, Hkv, D),
-        v_pool.reshape(nblk, page_size, Hkv, Dv),
+        k_pool.reshape(nblk, page_size, *k_pool.shape[1:]),
+        None if latent else v_pool.reshape(nblk, page_size, Hkv, -1),
         scale=scale,
         variant=variant,
         page_size=page_size,
         window=window,
+        v_width=v_width if latent else None,
         interpret=interpret,
     )
-    return o4.reshape(B, H, Dv)
+    return o4.reshape(B, H, -1)
 
 
 def quant_fused_paged_decode_attention_pallas(

@@ -203,9 +203,9 @@ def _paged_chunk(x, page_size):
 def fused_paged_prefill_attention_pallas(
     q: jax.Array,         # (B, H, C, D)
     k_chunk: jax.Array,   # (B, Hkv, C, D) this chunk's fresh KV
-    v_chunk: jax.Array,   # (B, Hkv, C, Dv)
+    v_chunk: jax.Array | None,  # (B, Hkv, C, Dv)
     k_pool: jax.Array,    # (pool_tokens, Hkv, D) flat physical pool
-    v_pool: jax.Array,    # (pool_tokens, Hkv, Dv)
+    v_pool: jax.Array | None,   # (pool_tokens, Hkv, Dv)
     block_tables: jax.Array,  # (B, max_blocks) int32, sentinel = pool_blocks
     lengths: jax.Array,   # (B,) tokens already resident
     n_valid: jax.Array,   # (B,) valid tokens in this chunk
@@ -215,31 +215,39 @@ def fused_paged_prefill_attention_pallas(
     variant: str = "exact",
     window: int | None = None,
     block_q: int = 128,
+    v_width: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused paged prefill: the kernel's index maps resolve physical blocks
-    from the block table per grid step (scalar prefetch), so the chunk
-    attends to the paged history straight out of the pool — no gathered
-    copy (DESIGN.md §10). The history is walked in 128-token blocks of
-    whole pages, up to each slot's length; windows mask in-kernel and
-    whole blocks below the window floor are skipped."""
+    from the block table per grid step, so the chunk attends to the paged
+    history straight out of the pool — no gathered copy (DESIGN.md §10).
+    The history is walked in 128-token blocks of whole pages, up to each
+    slot's length; windows mask in-kernel and whole blocks below the window
+    floor are skipped. ``v_pool=v_chunk=None``: the shared latent pool
+    (pool_tokens, D) and chunk (B, 1, C, D), V their first ``v_width``
+    columns (DESIGN.md §14)."""
     B, H, C, D = q.shape
-    pool_tokens, Hkv, _ = k_pool.shape
-    Dv = v_pool.shape[-1]
+    latent = v_pool is None
+    pool_tokens = k_pool.shape[0]
     interpret = _interpret_default(interpret)
     scale = float(1.0 / np.sqrt(D)) if scale is None else float(scale)
     assert pool_tokens % page_size == 0, (pool_tokens, page_size)
     nblk = pool_tokens // page_size
     bq = min(block_q, C)
+    if latent:
+        kn4 = _paged_chunk(k_chunk, page_size)[:, :, 0]
+        vn4 = v4 = None
+    else:
+        kn4 = _paged_chunk(k_chunk, page_size)
+        vn4 = _paged_chunk(v_chunk, page_size)
+        v4 = v_pool.reshape(nblk, page_size, *v_pool.shape[1:])
     o4 = paged_prefill_fwd_pallas(
         block_tables.astype(jnp.int32), _meta(lengths, n_valid),
         _pad_seq(q, C + (-C) % bq),
-        k_pool.reshape(nblk, page_size, Hkv, D),
-        v_pool.reshape(nblk, page_size, Hkv, Dv),
-        _paged_chunk(k_chunk, page_size),
-        _paged_chunk(v_chunk, page_size),
+        k_pool.reshape(nblk, page_size, *k_pool.shape[1:]), v4, kn4, vn4,
         scale=scale, variant=variant, window=window, page_size=page_size,
-        block_q=bq, interpret=interpret,
+        block_q=bq, v_width=v_width if latent else None,
+        interpret=interpret,
     )
     return o4[:, :, :C, :]
 

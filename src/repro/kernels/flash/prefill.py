@@ -277,6 +277,12 @@ def prefill_fwd_pallas(
 # Paged caches: a work list of 128-token blocks, each slot's only up to its
 # length, each page of a block brought by its own operand
 # ---------------------------------------------------------------------------
+# the shared-latent kernel's scoped VMEM: at 16 heads, 128 query rows and
+# 576 columns a block's queries, state and output take 18.8 MB on a v5e,
+# past the 16 MB default (tests/test_tpu_compile.py)
+LATENT_VMEM_BYTES = 32 * 1024 * 1024
+
+
 def block_pages(page_size):
     """Pages per block of the paged prefill kernel: 128 tokens' worth, so a
     block fills the lanes; one page where the page does not divide 128."""
@@ -286,10 +292,14 @@ def block_pages(page_size):
 def _paged_prefill_kernel(meta_ref, bt_ref, w_slot, w_qblk, w_kv, w_edge,
                           w_hist, w_chunk, q_ref, *refs, scale, variant, window,
                           page_size, pages, nkc, nkn, max_blocks, block_q,
-                          num_kv_heads, quant):
-    k_pages, v_pages = refs[:pages], refs[pages:2 * pages]
-    kn_ref, vn_ref = refs[2 * pages:2 * pages + 2]
-    refs = refs[2 * pages + 2:]
+                          num_kv_heads, quant, v_width):
+    if v_width is None:
+        k_pages, v_pages = refs[:pages], refs[pages:2 * pages]
+        kn_ref, vn_ref = refs[2 * pages:2 * pages + 2]
+        refs = refs[2 * pages + 2:]
+    else:  # one shared latent head: V is K's first v_width columns
+        k_pages, kn_ref = refs[:pages], refs[pages]
+        refs = refs[pages + 1:]
     if quant:
         ks_pages, vs_pages = refs[:pages], refs[pages:2 * pages]
         refs = refs[2 * pages:]
@@ -330,8 +340,11 @@ def _paged_prefill_kernel(meta_ref, bt_ref, w_slot, w_qblk, w_kv, w_edge,
         KV head g's pieces of the block, in key order."""
         for g in range(num_kv_heads):
             k_blk[g] = concat([x.astype(jnp.float32) for x in k_tiles(g)])
-            v_blk[g] = concat([x.astype(jnp.float32)
-                               for x in v_tiles(g)]).T
+            if v_width is None:
+                v_blk[g] = concat([x.astype(jnp.float32)
+                                   for x in v_tiles(g)]).T
+            else:
+                v_blk[g] = k_blk[g][:, :v_width].T
             if quant:  # (block, Hkv) scale rows -> a (block, 1) column
                 for sc, sc_blk in zip(scale_rows, (ks_blk, vs_blk)):
                     sc_blk[g] = sc[:, g:g + 1]
@@ -341,9 +354,12 @@ def _paged_prefill_kernel(meta_ref, bt_ref, w_slot, w_qblk, w_kv, w_edge,
     c0 = ki * width
     run_c = (ki < nkc) & (c0 < length)
 
+    def head_rows(ref, g):
+        return ref[0] if v_width is not None else ref[0, :, g, :]
+
     @pl.when(run_c)
     def _cache():
-        stage(lambda g: [r[0, :, g, :] for r in k_pages],
+        stage(lambda g: [head_rows(r, g) for r in k_pages],
               lambda g: [r[0, :, g, :] for r in v_pages],
               (concat([r[0] for r in ks_pages]),
                concat([r[0] for r in vs_pages])) if quant else None,
@@ -356,7 +372,7 @@ def _paged_prefill_kernel(meta_ref, bt_ref, w_slot, w_qblk, w_kv, w_edge,
 
     @pl.when(run_n)
     def _chunk():
-        stage(lambda g: [kn_ref[0, :, g, :]],
+        stage(lambda g: [head_rows(kn_ref, g)],
               lambda g: [vn_ref[0, :, g, :]],
               (ksn_ref[0], vsn_ref[0]) if quant else None,
               _chunk_tile_mask(n_valid, j0, r0, iota_r, iota_c,
@@ -443,7 +459,7 @@ def _work_list(meta, *, max_blocks, page_size, pages, nkc, nkn, nq,
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "variant", "window", "page_size", "block_q",
-                     "interpret"),
+                     "v_width", "interpret"),
 )
 def paged_prefill_fwd_pallas(
     bt,          # (B, max_blocks) int32 block tables
@@ -464,17 +480,25 @@ def paged_prefill_fwd_pallas(
     page_size,
     block_q,
     interpret,
+    v_width=None,
 ):
+    """``v4=vn4=None`` with ``v_width``: one shared latent head (MLA
+    absorbed, DESIGN.md §14): k4 (pool_blocks, page_size, D), kn4 (B,
+    C_padk, D), V their first ``v_width`` columns, read from K's pages."""
     B, H, Cq, D = q4.shape
-    nblk, _, Hkv, _ = k4.shape
-    Dv = v4.shape[-1]
+    latent = v4 is None
+    nblk = k4.shape[0]
+    Hkv = 1 if latent else k4.shape[2]
+    Dv = v_width if latent else v4.shape[-1]
     _, MB = bt.shape
     nq = Cq // block_q
     quant = ks3 is not None
+    assert not (latent and quant), "a quantized pool keeps its own V"
     pages = block_pages(page_size)
     width = pages * page_size
     nkc = -(-MB // pages)
-    chunk = [kn4, vn4] + ([ksn3, vsn3] if quant else [])
+    chunk = [kn4] + ([] if latent else [vn4]) + (
+        [ksn3, vsn3] if quant else [])
     pad = -kn4.shape[1] % width            # whole blocks of chunk tokens
     if pad:
         chunk = [jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
@@ -487,6 +511,7 @@ def paged_prefill_fwd_pallas(
         _paged_prefill_kernel, scale=scale, variant=variant, window=window,
         page_size=page_size, pages=pages, nkc=nkc, nkn=nkn, max_blocks=MB,
         block_q=block_q, num_kv_heads=Hkv, quant=quant,
+        v_width=v_width if latent else None,
     )
 
     # The index maps resolve the block table per step, from the latest step
@@ -512,13 +537,18 @@ def paged_prefill_fwd_pallas(
     def rows(t, meta, bt, slot, qblk, kv, edge, hist, chunk):
         return (slot[t], 0, qblk[t], 0)
 
-    in_specs = [
-        pl.BlockSpec((1, H, block_q, D), rows),
-        *[page_spec(i, Hkv, D) for i in range(pages)],
-        *[page_spec(i, Hkv, Dv) for i in range(pages)],
-        chunk_spec(Hkv, D), chunk_spec(Hkv, Dv),
-    ]
-    args = [q4, *[k4] * pages, *[v4] * pages, *chunk[:2]]
+    if latent:
+        in_specs = [pl.BlockSpec((1, H, block_q, D), rows),
+                    *[page_spec(i, D) for i in range(pages)], chunk_spec(D)]
+        args = [q4, *[k4] * pages, chunk[0]]
+    else:
+        in_specs = [
+            pl.BlockSpec((1, H, block_q, D), rows),
+            *[page_spec(i, Hkv, D) for i in range(pages)],
+            *[page_spec(i, Hkv, Dv) for i in range(pages)],
+            chunk_spec(Hkv, D), chunk_spec(Hkv, Dv),
+        ]
+        args = [q4, *[k4] * pages, *[v4] * pages, *chunk[:2]]
     scratch = [                                      # transposed state
         pltpu.VMEM((H, 1, block_q), jnp.float32),
         pltpu.VMEM((H, 1, block_q), jnp.float32),
@@ -545,5 +575,7 @@ def paged_prefill_fwd_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Cq, Dv), q4.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=LATENT_VMEM_BYTES) if latent else None,
         interpret=interpret,
     )(meta, bt, *work, *args)

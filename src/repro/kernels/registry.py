@@ -337,9 +337,21 @@ def dispatch_decode(spec: AttentionSpec, q, k_cache, v_cache, lengths, *,
     return fn(q, k_cache, v_cache, lengths, spec=spec, scale=scale)
 
 
+def _latent_operands(k_pool, v_width, *chunk):
+    """The shared-latent operands (``v_pool=None``) in the general paged
+    convention, for backends that do not read V inside K's rows: the pool
+    gains its one-head axis and V is materialized as K's first columns."""
+    k_pool = k_pool[:, None, :]
+    out = [k_pool, k_pool[..., :v_width]]
+    for k in chunk:
+        out += [k, k[..., :v_width]]
+    return out
+
+
 def dispatch_paged_prefill(spec: AttentionSpec, q, k_chunk, v_chunk, k_pool,
                            v_pool, rows, *, q_positions, chunk_valid, lengths,
-                           scale=None, block_tables=None, page_size=0):
+                           scale=None, block_tables=None, page_size=0,
+                           v_width=None):
     """Chunked prefill against a paged KV pool (DESIGN.md §7).
 
     q: (B, H, C, D) chunk queries; k_chunk/v_chunk: (B, Hkv, C, ·) this
@@ -350,24 +362,36 @@ def dispatch_paged_prefill(spec: AttentionSpec, q, k_chunk, v_chunk, k_pool,
     lengths: (B,) tokens already resident. The implementation gathers the
     history through ``rows`` and masks positionally exactly like the
     contiguous prefill path.
+
+    ``v_pool=v_chunk=None`` with ``v_width``: one shared KV head whose rows
+    carry V in their first ``v_width`` columns (MLA's absorbed latent,
+    DESIGN.md §14): k_pool is (pool_tokens, D) and k_chunk (B, 1, C, D).
+    The fused ``pallas`` backend reads V out of K's pages in place.
     """
-    fn = _lookup(_PAGED_PREFILL_IMPLS, spec.resolved_paged_impl(),
-                 "paged prefill")
+    name = spec.resolved_paged_impl()
+    fn = _lookup(_PAGED_PREFILL_IMPLS, name, "paged prefill")
     if _DISPATCH_SINK is not None:
-        qs, ks, vs = _shape(q), _shape(k_pool), _shape(v_pool)
+        qs, ks = _shape(q), _shape(k_pool)
         _DISPATCH_SINK("paged_prefill", spec, batch=qs[0], heads=qs[1],
-                       heads_kv=ks[1], d_qk=ks[-1], d_v=vs[-1],
+                       heads_kv=1 if v_pool is None else ks[1], d_qk=ks[-1],
+                       d_v=v_width if v_pool is None else _shape(v_pool)[-1],
                        kv_tokens=_shape(rows)[1], q_tokens=qs[2],
                        page_size=page_size)
+    kw = {}
+    if v_pool is None and name == "pallas" and block_tables is not None:
+        kw["v_width"] = v_width
+    elif v_pool is None:
+        k_pool, v_pool, k_chunk, v_chunk = _latent_operands(k_pool, v_width,
+                                                            k_chunk)
     return fn(q, k_chunk, v_chunk, k_pool, v_pool, rows, spec=spec,
               scale=scale, q_positions=q_positions, chunk_valid=chunk_valid,
               lengths=lengths, block_tables=block_tables,
-              page_size=page_size)
+              page_size=page_size, **kw)
 
 
 def dispatch_paged_decode(spec: AttentionSpec, q, k_pool, v_pool, rows,
                           lengths, *, scale=None, block_tables=None,
-                          page_size=0):
+                          page_size=0, v_width=None):
     """Single-token decode against a paged KV pool.
 
     q: (B, H, D); pools: (pool_tokens, Hkv, ·); rows: (B, L) physical rows
@@ -376,14 +400,30 @@ def dispatch_paged_decode(spec: AttentionSpec, q, k_pool, v_pool, rows,
     ``spec.window`` masks positions below ``lengths - window``.
     ``block_tables``/``page_size``, when provided, let fused backends
     resolve pool rows inside the kernel instead of gathering via ``rows``.
+    ``v_pool=None`` with ``v_width``: the shared-latent pool
+    (pool_tokens, D) of ``dispatch_paged_prefill``.
     """
-    fn = _lookup(_PAGED_DECODE_IMPLS, spec.resolved_paged_impl(),
-                 "paged decode")
+    name = spec.resolved_paged_impl()
+    fn = _lookup(_PAGED_DECODE_IMPLS, name, "paged decode")
     if _DISPATCH_SINK is not None:
-        qs, ks, vs = _shape(q), _shape(k_pool), _shape(v_pool)
+        qs, ks = _shape(q), _shape(k_pool)
         _DISPATCH_SINK("paged_decode", spec, batch=qs[0], heads=qs[1],
-                       heads_kv=ks[1], d_qk=ks[-1], d_v=vs[-1],
+                       heads_kv=1 if v_pool is None else ks[1], d_qk=ks[-1],
+                       d_v=v_width if v_pool is None else _shape(v_pool)[-1],
                        kv_tokens=_shape(rows)[1], q_tokens=1,
                        page_size=page_size)
+    kw = {}
+    if v_pool is None and name == "pallas" and block_tables is not None:
+        kw["v_width"] = v_width
+    elif v_pool is None:
+        k_pool, v_pool = _latent_operands(k_pool, v_width)
     return fn(q, k_pool, v_pool, rows, lengths, spec=spec, scale=scale,
-              block_tables=block_tables, page_size=page_size)
+              block_tables=block_tables, page_size=page_size, **kw)
+
+
+def dispatch_experts(impl: str) -> None:
+    """Report one expert-FFN dispatch by its backend (``layers/moe.py``:
+    dropless, scatter or balanced) to the installed sink, beside the
+    attention dispatches (DESIGN.md §12)."""
+    if _DISPATCH_SINK is not None:
+        _DISPATCH_SINK("expert", impl)

@@ -1,6 +1,8 @@
 """Shared layer utilities: initializers, norms, activations."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -39,11 +41,11 @@ def layernorm(params, x, eps=1e-6):
             + params["bias"].astype(jnp.float32)).astype(x.dtype)
 
 
-def make_norm(kind: str):
+def make_norm(kind: str, eps: float = 1e-6):
     if kind == "rmsnorm":
-        return rmsnorm_init, rmsnorm
+        return rmsnorm_init, functools.partial(rmsnorm, eps=eps)
     if kind == "layernorm":
-        return layernorm_init, layernorm
+        return layernorm_init, functools.partial(layernorm, eps=eps)
     raise ValueError(kind)
 
 

@@ -1,44 +1,67 @@
 """Mixture-of-Experts FFN with top-k routing.
 
-Two dispatch implementations:
+Three dispatch implementations:
 
+* ``dropless`` — the serving path (DESIGN.md §14): every routed row is
+  computed. The rows routed to the experts this layer holds are sorted by
+  expert and go through one grouped matmul per projection
+  (``kernels/experts.py``); no capacity, so a token's output does not
+  depend on which other tokens share its batch.
 * ``scatter``  — real token routing: top-k -> per-expert capacity positions
   via cumulative counts -> scatter into an (E, C, D) buffer -> batched expert
   GEMMs -> weighted combine. Tokens over capacity are dropped (standard
-  capacity-factor semantics). Used by tests/examples.
+  capacity-factor semantics). Used by training, tests and examples.
 * ``balanced`` — deterministic round-robin assignment with router-derived
   combine weights. Identical FLOP/byte/collective profile to perfectly
   balanced routing with zero scatter overhead; used by the trillion-class
   dry-runs where the scatter gather/scatter HLOs dominate compile time.
   (Recorded in DESIGN.md; routing quality is irrelevant to the dry-run.)
 
-The router softmax stays exact (not ExpMul): it is O(E) per token — a
-negligible cost next to attention — and routing decisions are
-quality-critical.
+Routers (``MoEConfig.router``): ``softmax`` over the top-k logits, or
+DeepSeek-V3's ``sigmoid`` (noaux_tc): sigmoid scores, the top-k chosen on
+score plus a per-expert correction bias (``e_bias``), weighted by the
+chosen experts' unbiased scores, normalised over the k and scaled by
+``routed_scaling_factor``. Either stays exact and in float32 (not ExpMul):
+it is O(E) per token, negligible next to attention, and routing decisions
+are quality-critical.
+
+An expert share (``MoEConfig.held_experts``): the layer holds the routed
+experts [start, stop) of all ``num_experts``, routes over all of them and
+adds only its own experts' part of the result, as one chip of an
+expert-parallel group does before the exchange. Shared experts
+(``num_shared_experts``) are a dense SwiGLU every token passes through.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.experts import grouped_ffn, row_tile
+from repro.kernels.registry import dispatch_experts
 from repro.layers.common import activation_fn, dense_init
+from repro.layers.mlp import mlp_apply, mlp_init
 
 
 def moe_init(key, cfg, dtype):
     m = cfg.moe
     d = cfg.d_model
+    E = m.num_held
     ks = jax.random.split(key, 5)
     gated = cfg.activation in ("swiglu", "geglu")
     p = {
         "router": dense_init(ks[0], (d, m.num_experts), dtype),
-        "w_up": dense_init(ks[1], (m.num_experts, d, m.d_ff), dtype),
-        "w_down": dense_init(ks[2], (m.num_experts, m.d_ff, d), dtype),
+        "w_up": dense_init(ks[1], (E, d, m.d_ff), dtype),
+        "w_down": dense_init(ks[2], (E, m.d_ff, d), dtype),
     }
     if gated:
-        p["w_gate"] = dense_init(ks[3], (m.num_experts, d, m.d_ff), dtype)
+        p["w_gate"] = dense_init(ks[3], (E, d, m.d_ff), dtype)
+    if m.router == "sigmoid":
+        p["e_bias"] = jnp.zeros((m.num_experts,), jnp.float32)
+    if m.num_shared_experts:
+        p["shared"] = mlp_init(jax.random.fold_in(key, 5), d,
+                               m.num_shared_experts * m.d_ff, cfg.activation,
+                               dtype)
     if m.dense_residual:
-        from repro.layers.mlp import mlp_init
-
         p["dense"] = mlp_init(ks[4], d, m.dense_d_ff, cfg.activation, dtype)
     return p
 
@@ -98,14 +121,48 @@ def _expert_ffn_ep(params, xe, cfg):
     )(params["w_up"], wg_arg, params["w_down"], xe)
 
 
-def _route(params, x2, m):
+def route(params, x2, m):
+    """(T, D) -> combine weights (T, k) float32 and expert ids (T, k) over
+    all ``m.num_experts`` (module docstring)."""
     from repro.sharding.constraints import constrain
 
     logits = (x2.astype(jnp.float32) @ params["router"].astype(jnp.float32))
     logits = constrain(logits, "batch", None)
-    top_w, top_ids = jax.lax.top_k(logits, m.top_k)          # (T, k)
-    top_w = jax.nn.softmax(top_w, axis=-1)                   # exact softmax
-    return top_w, top_ids
+    if m.router == "softmax":
+        top_w, top_ids = jax.lax.top_k(logits, m.top_k)      # (T, k)
+        return jax.nn.softmax(top_w, axis=-1), top_ids       # exact softmax
+    if m.router != "sigmoid":
+        raise ValueError(f"unknown router {m.router!r}")
+    scores = jax.nn.sigmoid(logits)
+    _, top_ids = jax.lax.top_k(scores + params["e_bias"], m.top_k)
+    top_w = jnp.take_along_axis(scores, top_ids, axis=1)
+    top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    return top_w * m.routed_scaling_factor, top_ids
+
+
+def _dropless(params, x2, top_w, top_ids, m, activation):
+    """The held experts' part of the routed output, (T, D) float32: the
+    rows routed to held experts sorted by expert, one grouped matmul per
+    projection, weighted and added back to their tokens."""
+    T, _ = x2.shape
+    k = m.top_k
+    start, _ = m.held_range
+    E = m.num_held
+    local = top_ids.reshape(-1) - start
+    held = (local >= 0) & (local < E)
+    order = jnp.argsort(jnp.where(held, local, E), stable=True)
+    # every held row sorts before every other: at most T * min(k, E) rows
+    rows = T * min(k, E)
+    rows += -rows % row_tile(rows)
+    order = jnp.pad(order, (0, max(0, rows - T * k)))[:rows]
+    group_sizes = jnp.bincount(jnp.where(held, local, E), length=E + 1)[:E]
+    ys = grouped_ffn(x2[order // k], params, group_sizes.astype(jnp.int32),
+                     activation_fn(activation))
+    valid = jnp.arange(rows) < jnp.sum(group_sizes)
+    w = top_w.reshape(-1)[order]
+    contrib = jnp.where(valid[:, None], ys * w[:, None], 0.0)
+    return jnp.zeros((T, x2.shape[1]), jnp.float32).at[order // k].add(
+        contrib)
 
 
 def moe_apply(params, x, cfg, *, impl="scatter"):
@@ -114,9 +171,16 @@ def moe_apply(params, x, cfg, *, impl="scatter"):
     B, S, D = x.shape
     T = B * S
     x2 = x.reshape(T, D)
-    top_w, top_ids = _route(params, x2, m)
+    top_w, top_ids = route(params, x2, m)
+    dispatch_experts(impl)
+    if impl != "dropless" and m.num_held != m.num_experts:
+        raise NotImplementedError(
+            f"moe impl {impl!r} computes all experts; a layer that holds "
+            f"experts {m.held_range} of {m.num_experts} serves dropless")
 
-    if impl == "balanced":
+    if impl == "dropless":
+        y = _dropless(params, x2, top_w, top_ids, m, cfg.activation)
+    elif impl == "balanced":
         # deterministic balanced dispatch: token-copies map to experts in
         # contiguous slabs (copy i -> expert i // C), combined with router
         # weights. Cost-model exact, routing-content free (dry-run only).
@@ -186,33 +250,34 @@ def moe_apply(params, x, cfg, *, impl="scatter"):
         )
     else:
         raise ValueError(impl)
+    return _add_dense_paths(params, x2, y, cfg).reshape(B, S, D).astype(
+        x.dtype)
 
-    if m.dense_residual:
-        from repro.layers.mlp import mlp_apply
 
-        y = y + mlp_apply(params["dense"], x2, cfg.activation).astype(y.dtype)
-    return y.reshape(B, S, D).astype(x.dtype)
+def _add_dense_paths(params, x2, y, cfg):
+    """y plus what every token computes alike: the shared experts and
+    arctic's dense residual."""
+    for name in ("shared", "dense"):
+        if name in params:
+            y = y + mlp_apply(params[name], x2, cfg.activation).astype(
+                y.dtype)
+    return y
 
 
 def moe_ref(params, x, cfg):
-    """Dense oracle: every expert on every token, masked combine (small cfgs)."""
+    """Dense oracle: every held expert on every token, masked combine
+    (small cfgs)."""
     m = cfg.moe
     B, S, D = x.shape
     T = B * S
     x2 = x.reshape(T, D)
-    top_w, top_ids = _route(params, x2, m)
-    xe = jnp.broadcast_to(x2, (m.num_experts, T, D))
+    top_w, top_ids = route(params, x2, m)
+    start, _ = m.held_range
+    xe = jnp.broadcast_to(x2, (m.num_held, T, D))
     ye = _expert_ffn(params, xe, cfg.activation)             # (E, T, D)
-    w_full = jnp.zeros((T, m.num_experts), jnp.float32)
-    w_full = jnp.take_along_axis(
-        w_full, top_ids, axis=1
-    ) * 0  # noop to keep shape; use scatter below
     w_full = jnp.zeros((T, m.num_experts), jnp.float32).at[
         jnp.arange(T)[:, None], top_ids
-    ].add(top_w)
+    ].add(top_w)[:, start:start + m.num_held]
     y = jnp.einsum("etd,te->td", ye.astype(jnp.float32), w_full)
-    if m.dense_residual:
-        from repro.layers.mlp import mlp_apply
-
-        y = y + mlp_apply(params["dense"], x2, cfg.activation).astype(jnp.float32)
-    return y.reshape(B, S, D).astype(x.dtype)
+    return _add_dense_paths(params, x2, y, cfg).reshape(B, S, D).astype(
+        x.dtype)

@@ -5,6 +5,13 @@ a leading axis so the whole depth runs under one ``lax.scan`` — this keeps
 the HLO (and 512-device SPMD compile time) independent of depth, and remat
 applies per-unit. Heterogeneous patterns (recurrentgemma's r,r,a;
 xLSTM's m...s) scan over multi-block units in true layer order.
+
+``cfg.first_dense_layers`` leading attention blocks with a dense FFN of
+width ``cfg.d_ff`` (DeepSeek's first_k_dense_replace) sit before the unit
+stack, stacked on a leading axis of their own (``params["lead"]``), and
+run first. Their caches are the first entries of the same stacked
+cache as the units', so one state covers all ``num_layers`` layers and
+pool init, copy-on-write and poisoning need no case of their own.
 """
 from __future__ import annotations
 
@@ -49,9 +56,22 @@ def _unit(cfg: ModelConfig):
 
 
 def _n_units(cfg: ModelConfig, total=None):
-    total = cfg.num_layers if total is None else total
+    total = cfg.num_layers - cfg.first_dense_layers if total is None else total
     assert total % len(_unit(cfg)) == 0, (total, _unit(cfg))
     return total // len(_unit(cfg))
+
+
+def _n_cache_units(cfg: ModelConfig):
+    """Entries of each stacked cache: the leading dense layers', then the
+    units'."""
+    return cfg.first_dense_layers + _n_units(cfg)
+
+
+def _lead_cfg(cfg: ModelConfig):
+    if cfg.first_dense_layers and _unit(cfg) != ("attn",):
+        raise NotImplementedError("leading dense layers before a "
+                                  "non-attention block pattern")
+    return cfg.replace(moe=None)
 
 
 def _stack_init(key, n, fn):
@@ -64,7 +84,7 @@ def _stack_init(key, n, fn):
 # ---------------------------------------------------------------------------
 def init_model(key, cfg: ModelConfig):
     pd = _pdtype(cfg)
-    norm_init, _ = make_norm(cfg.norm)
+    norm_init, _ = make_norm(cfg.norm, cfg.norm_eps)
     keys = jax.random.split(key, 8)
     params = {
         "embed": embed_init(keys[0], cfg, pd),
@@ -104,6 +124,10 @@ def init_model(key, cfg: ModelConfig):
             )
             for i, kind in enumerate(_unit(cfg))
         )
+        if cfg.first_dense_layers:
+            params["lead"] = (_stack_init(
+                keys[4], cfg.first_dense_layers,
+                lambda k: block_init(k, _lead_cfg(cfg), "attn", pd)),)
     return params
 
 
@@ -148,7 +172,7 @@ def _dec_block_apply(p, x, cfg, *, positions, enc_out, moe_impl):
     from repro.layers.mla import mla_apply
     from repro.layers.moe import moe_apply
 
-    _, norm = make_norm(cfg.norm)
+    _, norm = make_norm(cfg.norm, cfg.norm_eps)
     fn = mla_apply if cfg.mla else attn_apply
     x = x + fn(p["mix"], norm(p["norm_mix"], x), cfg,
                positions=positions, causal=True)
@@ -176,7 +200,7 @@ def forward(params, batch, cfg: ModelConfig, *, moe_impl="scatter"):
 
     Returns logits (B, S_total, V).
     """
-    _, norm = make_norm(cfg.norm)
+    _, norm = make_norm(cfg.norm, cfg.norm_eps)
     tokens = batch["tokens"]
     x = embed_apply(params["embed"], tokens, cfg).astype(_dtype(cfg))
     if cfg.frontend and "frontend_embeds" in batch and not cfg.encoder_layers:
@@ -199,6 +223,10 @@ def forward(params, batch, cfg: ModelConfig, *, moe_impl="scatter"):
             positions=positions, enc_out=enc_out, moe_impl=moe_impl,
         )
     else:
+        if "lead" in params:
+            x = _run_stack(params["lead"], x, cfg, ("attn",),
+                           positions=positions, causal=True,
+                           moe_impl=moe_impl)
         x = _run_stack(
             params["units"], x, cfg, _unit(cfg),
             positions=positions, causal=True, moe_impl=moe_impl,
@@ -257,7 +285,7 @@ def init_decode_state(cfg: ModelConfig, batch, max_len, *, enc_len=None):
             "enc_len": jnp.zeros((batch,), jnp.int32),
         }
         return state
-    nu = _n_units(cfg)
+    nu = _n_cache_units(cfg)
     return {
         "caches": tuple(stacked_cache(kind, nu) for kind in _unit(cfg)),
     }
@@ -279,7 +307,7 @@ def init_paged_state(cfg: ModelConfig, slots, pool_blocks, page_size):
                                   "the contiguous layout")
     dt = _dtype(cfg)
     pool_tokens = pool_blocks * page_size
-    nu = _n_units(cfg)
+    nu = _n_cache_units(cfg)
 
     def stacked_cache(kind):
         one = block_init_paged_cache(cfg, kind, pool_tokens, slots, dt)
@@ -363,7 +391,7 @@ def poison_paged_block(state, cfg: ModelConfig, block, *, page_size,
 
 def encode_for_decode(params, state, frontend_embeds, enc_lengths, cfg):
     """Run the encoder once and stash per-layer cross K/V (enc-dec serving)."""
-    _, norm = make_norm(cfg.norm)
+    _, norm = make_norm(cfg.norm, cfg.norm_eps)
     enc_in = frontend_embeds.astype(_dtype(cfg)) @ params["frontend_proj"]
     B, Se, _ = enc_in.shape
     enc_pos = jnp.broadcast_to(jnp.arange(Se), (B, Se))
@@ -381,39 +409,52 @@ def encode_for_decode(params, state, frontend_embeds, enc_lengths, cfg):
     return state
 
 
-def _scan_unit_caches(params_units, caches, x, cfg, step_fn):
-    """Run the unit stack with the KV caches riding the scan carry.
+def _scan_unit_caches(params, caches, x, cfg, step_fn):
+    """Run the layer stack with the KV caches riding the scan carry: the
+    leading dense layers (``params["lead"]``), then the units.
 
-    Caches are updated with dynamic-update-slice at the unit index: with
+    Caches are updated with dynamic-update-slice at the layer's index: with
     donated state buffers this is a true in-place update (the previous
     xs->ys restacking materialized the whole stacked cache twice per token —
-    §Perf gemma decode). ``step_fn(p_block, cache_block, x, kind) ->
-    (new_cache, x)`` supplies the per-block computation; prefill, decode,
+    §Perf gemma decode). The leading layers' caches are written back after
+    the scan: written before it, the stacked caches would take a third copy
+    on the device between the two. ``step_fn(p_block, cache_block, x, kind)
+    -> (new_cache, x)`` supplies the per-block computation; prefill, decode,
     and their paged variants all share this scan.
     """
+    def read(bufs, idx):
+        return jax.tree.map(
+            lambda buf: jax.lax.dynamic_index_in_dim(buf, idx, 0,
+                                                     keepdims=False), bufs)
+
+    def write(bufs, idx, new):
+        return jax.tree.map(
+            lambda buf, n: jax.lax.dynamic_update_index_in_dim(
+                buf, n.astype(buf.dtype), idx, 0), bufs, new)
+
+    lead = []
+    for i in range(cfg.first_dense_layers):
+        p_l = jax.tree.map(lambda a: a[i], params["lead"][0])
+        c_new, x = step_fn(p_l, read(caches[0], i), x, "attn")
+        lead.append(c_new)
+
     def unit_body(carry, xs):
         x, caches = carry
         p_l, idx = xs
         new_caches = []
         for pos, kind in enumerate(_unit(cfg)):
-            c_l = jax.tree.map(
-                lambda buf: jax.lax.dynamic_index_in_dim(
-                    buf, idx, 0, keepdims=False),
-                caches[pos],
-            )
-            c_new, x = step_fn(p_l[pos], c_l, x, kind)
-            new_caches.append(jax.tree.map(
-                lambda buf, n: jax.lax.dynamic_update_index_in_dim(
-                    buf, n.astype(buf.dtype), idx, 0),
-                caches[pos], c_new,
-            ))
+            c_new, x = step_fn(p_l[pos], read(caches[pos], idx), x, kind)
+            new_caches.append(write(caches[pos], idx, c_new))
         return (x, tuple(new_caches)), None
 
     (x, new_caches), _ = jax.lax.scan(
         unit_body, (x, caches),
-        (params_units, jnp.arange(_n_units(cfg))),
+        (params["units"], cfg.first_dense_layers + jnp.arange(_n_units(cfg))),
     )
-    return x, new_caches
+    first = new_caches[0]
+    for i, c_new in enumerate(lead):
+        first = write(first, i, c_new)
+    return x, (first,) + tuple(new_caches[1:])
 
 
 def prefill(params, state, tokens, lengths, n_valid, cfg: ModelConfig):
@@ -432,12 +473,12 @@ def prefill(params, state, tokens, lengths, n_valid, cfg: ModelConfig):
         raise NotImplementedError("chunked prefill targets decoder-only "
                                   "configs; encoder-decoder serving uses "
                                   "encode_for_decode + decode_step")
-    _, norm = make_norm(cfg.norm)
+    _, norm = make_norm(cfg.norm, cfg.norm_eps)
     B, C = tokens.shape
     x = embed_apply(params["embed"], tokens, cfg).astype(_dtype(cfg))
 
     x, new_caches = _scan_unit_caches(
-        params["units"], state["caches"], x, cfg,
+        params, state["caches"], x, cfg,
         lambda p, c, x, kind: block_prefill(p, c, x, cfg, kind, lengths,
                                             n_valid),
     )
@@ -461,7 +502,7 @@ def prefill_paged(params, state, tokens, lengths, n_valid, block_tables,
     if cfg.encoder_layers:
         raise NotImplementedError("paged prefill targets decoder-only "
                                   "configs")
-    _, norm = make_norm(cfg.norm)
+    _, norm = make_norm(cfg.norm, cfg.norm_eps)
     B, C = tokens.shape
     x = embed_apply(params["embed"], tokens, cfg).astype(_dtype(cfg))
     rows = slot_rows(block_tables, page_size)
@@ -469,7 +510,7 @@ def prefill_paged(params, state, tokens, lengths, n_valid, block_tables,
     chunk_rows = token_rows(block_tables, positions, page_size)
 
     x, new_caches = _scan_unit_caches(
-        params["units"], state["caches"], x, cfg,
+        params, state["caches"], x, cfg,
         lambda p, c, x, kind: block_paged_prefill(p, c, x, cfg, kind,
                                                   lengths, n_valid, rows,
                                                   chunk_rows, block_tables,
@@ -491,13 +532,13 @@ def decode_step_paged(params, state, tokens1, lengths, block_tables,
     """
     if cfg.encoder_layers:
         raise NotImplementedError("paged decode targets decoder-only configs")
-    _, norm = make_norm(cfg.norm)
+    _, norm = make_norm(cfg.norm, cfg.norm_eps)
     x = embed_apply(params["embed"], tokens1[:, None], cfg)[:, 0].astype(_dtype(cfg))
     rows = slot_rows(block_tables, page_size)
     write_row = token_rows(block_tables, lengths, page_size)
 
     x, new_caches = _scan_unit_caches(
-        params["units"], state["caches"], x, cfg,
+        params, state["caches"], x, cfg,
         lambda p, c, x, kind: block_paged_decode_step(p, c, x, cfg, kind,
                                                       lengths, rows,
                                                       write_row, block_tables,
@@ -510,7 +551,7 @@ def decode_step_paged(params, state, tokens1, lengths, block_tables,
 
 def decode_step(params, state, tokens1, lengths, cfg: ModelConfig):
     """One serving step: tokens1 (B,) -> logits (B, V), updated state."""
-    _, norm = make_norm(cfg.norm)
+    _, norm = make_norm(cfg.norm, cfg.norm_eps)
     x = embed_apply(params["embed"], tokens1[:, None], cfg)[:, 0].astype(_dtype(cfg))
 
     if cfg.encoder_layers:
@@ -535,7 +576,7 @@ def decode_step(params, state, tokens1, lengths, cfg: ModelConfig):
         new_state["caches"] = (c_new,)
     else:
         x, new_caches = _scan_unit_caches(
-            params["units"], state["caches"], x, cfg,
+            params, state["caches"], x, cfg,
             lambda p, c, x, kind: block_decode_step(p, c, x, cfg, kind,
                                                     lengths),
         )

@@ -53,7 +53,7 @@ def _has_ffn(cfg, kind):
 
 
 def block_init(key, cfg, kind, dtype):
-    norm_init, _ = make_norm(cfg.norm)
+    norm_init, _ = make_norm(cfg.norm, cfg.norm_eps)
     ks = jax.random.split(key, 4)
     p = {"norm_mix": norm_init(cfg.d_model, dtype)}
     if kind == "attn":
@@ -77,7 +77,7 @@ def block_init(key, cfg, kind, dtype):
 
 def block_apply(params, x, cfg, kind, *, positions=None, causal=True,
                 moe_impl="scatter"):
-    _, norm = make_norm(cfg.norm)
+    _, norm = make_norm(cfg.norm, cfg.norm_eps)
     h = norm(params["norm_mix"], x)
     window = cfg.window if kind == "attn" and cfg.window else None
     if kind == "attn":
@@ -92,13 +92,18 @@ def block_apply(params, x, cfg, kind, *, positions=None, causal=True,
         h = slstm_apply(params["mix"], h, cfg)
     x = x + h
     if "ffn" in params:
-        h = norm(params["norm_ffn"], x)
-        if cfg.moe is not None:
-            h = moe_apply(params["ffn"], h, cfg, impl=moe_impl)
-        else:
-            h = mlp_apply(params["ffn"], h, cfg.activation)
-        x = x + h
+        x = x + _ffn(params["ffn"], norm(params["norm_ffn"], x), cfg,
+                     moe_impl)
     return x
+
+
+def _ffn(params, h, cfg, moe_impl):
+    """The block's FFN: experts where it holds a router (MoE layers), the
+    dense MLP otherwise (dense models, and the leading dense layers of an
+    MoE stack). Serving paths pass ``moe_impl="dropless"``."""
+    if "router" in params:
+        return moe_apply(params, h, cfg, impl=moe_impl)
+    return mlp_apply(params, h, cfg.activation)
 
 
 def block_init_cache(cfg, kind, batch, max_len, dtype):
@@ -144,13 +149,14 @@ def block_paged_prefill(params, cache, x, cfg, kind, lengths, n_valid, rows,
     Recurrent kinds ignore them and run the same gated single-token scan as
     the contiguous path.
     """
-    _, norm = make_norm(cfg.norm)
+    _, norm = make_norm(cfg.norm, cfg.norm_eps)
     if kind != "attn":
         return block_prefill(params, cache, x, cfg, kind, lengths, n_valid)
     h = norm(params["norm_mix"], x)
     if cfg.mla:
         cache, h = mla_paged_prefill_step(params["mix"], cache, h, cfg,
-                                          lengths, n_valid, rows, chunk_rows)
+                                          lengths, n_valid, rows, chunk_rows,
+                                          block_tables, page_size)
     else:
         window = cfg.window if cfg.window else None
         cache, h = attn_paged_prefill_step(params["mix"], cache, h, cfg,
@@ -160,12 +166,8 @@ def block_paged_prefill(params, cache, x, cfg, kind, lengths, n_valid, rows,
                                            page_size=page_size)
     x = x + h
     if "ffn" in params:
-        h = norm(params["norm_ffn"], x)
-        if cfg.moe is not None:
-            h = moe_apply(params["ffn"], h, cfg, impl="scatter")
-        else:
-            h = mlp_apply(params["ffn"], h, cfg.activation)
-        x = x + h
+        x = x + _ffn(params["ffn"], norm(params["norm_ffn"], x), cfg,
+                     "dropless")
     return cache, x
 
 
@@ -174,11 +176,12 @@ def block_paged_decode_step(params, cache, x1, cfg, kind, lengths, rows,
     """Single-token decode through one residual block, paged KV variant."""
     if kind != "attn":
         return block_decode_step(params, cache, x1, cfg, kind, lengths)
-    _, norm = make_norm(cfg.norm)
+    _, norm = make_norm(cfg.norm, cfg.norm_eps)
     h = norm(params["norm_mix"], x1)
     if cfg.mla:
         cache, h = mla_paged_decode_step(params["mix"], cache, h, cfg,
-                                         lengths, rows, write_row)
+                                         lengths, rows, write_row,
+                                         block_tables, page_size)
     else:
         window = cfg.window if cfg.window else None
         cache, h = attn_paged_decode_step(params["mix"], cache, h, cfg,
@@ -189,11 +192,7 @@ def block_paged_decode_step(params, cache, x1, cfg, kind, lengths, rows,
     x1 = x1 + h
     if "ffn" in params:
         h = norm(params["norm_ffn"], x1)
-        if cfg.moe is not None:
-            h = moe_apply(params["ffn"], h[:, None, :], cfg, impl="scatter")[:, 0]
-        else:
-            h = mlp_apply(params["ffn"], h, cfg.activation)
-        x1 = x1 + h
+        x1 = x1 + _ffn(params["ffn"], h[:, None, :], cfg, "dropless")[:, 0]
     return cache, x1
 
 
@@ -207,7 +206,7 @@ def block_prefill(params, cache, x, cfg, kind, lengths, n_valid):
     sequential state) scan the single-token step with per-token validity
     gating — exact, just not parallel over the chunk.
     """
-    _, norm = make_norm(cfg.norm)
+    _, norm = make_norm(cfg.norm, cfg.norm_eps)
     if kind == "attn":
         h = norm(params["norm_mix"], x)
         if cfg.mla:
@@ -219,12 +218,8 @@ def block_prefill(params, cache, x, cfg, kind, lengths, n_valid):
                                          lengths, n_valid, window=window)
         x = x + h
         if "ffn" in params:
-            h = norm(params["norm_ffn"], x)
-            if cfg.moe is not None:
-                h = moe_apply(params["ffn"], h, cfg, impl="scatter")
-            else:
-                h = mlp_apply(params["ffn"], h, cfg.activation)
-            x = x + h
+            x = x + _ffn(params["ffn"], norm(params["norm_ffn"], x), cfg,
+                         "dropless")
         return cache, x
 
     def tok_body(carry, i):
@@ -245,7 +240,7 @@ def block_prefill(params, cache, x, cfg, kind, lengths, n_valid):
 
 
 def block_decode_step(params, cache, x1, cfg, kind, lengths):
-    _, norm = make_norm(cfg.norm)
+    _, norm = make_norm(cfg.norm, cfg.norm_eps)
     h = norm(params["norm_mix"], x1)
     if kind == "attn":
         if cfg.mla:
@@ -269,9 +264,5 @@ def block_decode_step(params, cache, x1, cfg, kind, lengths):
     x1 = x1 + h
     if "ffn" in params:
         h = norm(params["norm_ffn"], x1)
-        if cfg.moe is not None:
-            h = moe_apply(params["ffn"], h[:, None, :], cfg, impl="scatter")[:, 0]
-        else:
-            h = mlp_apply(params["ffn"], h, cfg.activation)
-        x1 = x1 + h
+        x1 = x1 + _ffn(params["ffn"], h[:, None, :], cfg, "dropless")[:, 0]
     return cache, x1

@@ -456,12 +456,15 @@ def make_dispatch_sink(registry: MetricsRegistry):
 
     The sink runs at Python dispatch time — 1:1 with attention calls for
     eager callers, once per jit trace for compiled callers (documented in
-    DESIGN.md §12).
+    DESIGN.md §12). Attention dispatches count into
+    ``attention_dispatch_total``; expert-FFN dispatches (kind ``expert``,
+    ``spec`` the backend's name) into ``expert_dispatch_total{impl}``.
     """
 
-    def sink(kind: str, spec, *, batch: int, heads: int, heads_kv: int,
-             d_qk: int, d_v: int, kv_tokens: int, q_tokens: int,
-             page_size: int = 0):
+    def sink(kind: str, spec, *, page_size: int = 0, **shape):
+        if kind == "expert":
+            registry.counter("expert_dispatch_total", impl=spec).inc()
+            return
         layout = "paged" if kind.startswith("paged") else "contiguous"
         registry.counter("attention_dispatch_total",
                          **_spec_labels(kind, spec, layout, page_size)).inc()

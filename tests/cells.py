@@ -109,8 +109,9 @@ def _skip_reason(kv_dtype, layout, family, mode) -> str:
                 "dispatch pins kv_dtype=fp32 so the registry never "
                 "double-quantizes (DESIGN.md §8)")
     if family == "mla" and layout == "paged":
-        return ("MLA pages the latent pool; the expanded-KV dispatch is "
-                "contiguous by construction (DESIGN.md §7)")
+        return ("MLA's paged path dispatches one shared latent head with V "
+                "inside K's rows (DESIGN.md §14, tests/test_moonlight.py); "
+                "this matrix's MLA shape is per-head expanded K/V")
     if family == "windowed" and mode == "forward":
         # not a hole — forward windows are covered tightly by
         # test_kernel_flash / test_arch_smoke; the serving modes below are

@@ -142,6 +142,7 @@ def test_param_counts_match_published_class():
         "qwen2-0.5b": (4.4e8, 6.5e8),
         "qwen1.5-0.5b": (4.4e8, 6.8e8),
         "minicpm3-4b": (3.5e9, 4.8e9),
+        "moonlight-16b-a3b": (1.55e10, 1.65e10),   # 15.96B published
         "xlstm-1.3b": (1.0e9, 1.8e9),
         "recurrentgemma-2b": (2.2e9, 3.4e9),
         "kimi-k2-1t-a32b": (0.9e12, 1.2e12),

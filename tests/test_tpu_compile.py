@@ -152,3 +152,55 @@ def test_flash_forward_compiles(one_chip, variant):
 def test_expmul_tile_compiles(one_chip):
     _compile(one_chip, lambda x, v: expmul_pallas(x, v, interpret=False),
              ((S,), jnp.float32), ((S, 2 * D), jnp.float32))
+
+
+# MLA's absorbed latent as moonlight-16b-a3b serves it (bench/configs): one
+# shared KV head of kv_lora_rank + rope = 576 columns, V its first 512,
+# read from K's page; 16 query heads in the group; 32 slots of 3072 tokens
+LATENT = dict(slots=32, mb=3072 // PS, heads=16, dk=576, dv=512)
+
+
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_latent_paged_decode_compiles(one_chip, variant):
+    n, mb, h, dk, dv = LATENT.values()
+    text = _compile(one_chip,
+                    lambda q, pool, bt, ln:
+                    fused_paged_decode_attention_pallas(
+                        q, pool, None, bt, ln, page_size=PS, variant=variant,
+                        v_width=dv, interpret=False),
+                    ((n, h, dk), VALUES), ((n * mb * PS, dk), VALUES),
+                    ((n, mb), jnp.int32), ((n,), jnp.int32))
+    assert "paged_decode_fwd_pallas" in text
+
+
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_latent_paged_prefill_compiles(one_chip, variant):
+    n, mb, h, dk, dv = LATENT.values()
+    text = _compile(one_chip,
+                    lambda q, kn, pool, bt, ln, nv:
+                    fused_paged_prefill_attention_pallas(
+                        q, kn, None, pool, None, bt, ln, nv, page_size=PS,
+                        variant=variant, v_width=dv, interpret=False),
+                    ((n, h, C, dk), VALUES), ((n, 1, C, dk), VALUES),
+                    ((n * mb * PS, dk), VALUES), ((n, mb), jnp.int32),
+                    ((n,), jnp.int32), ((n,), jnp.int32))
+    assert "paged_prefill_fwd_pallas" in text
+
+
+# the expert FFN's grouped matmuls at moonlight-16b-a3b's widths: 8 held
+# experts, d 2048 -> 1408 -> 2048, for a decode tick's 32 x 6 routed rows
+# and a prefill tick's 32 x 128 x 6
+@pytest.mark.parametrize("rows", (32 * 6, 32 * 128 * 6))
+def test_expert_gmm_compiles(one_chip, rows):
+    from repro.kernels.experts import grouped_ffn, row_tile
+
+    E, d, f = 8, 2048, 1408
+    m = rows + -rows % row_tile(rows)
+    text = _compile(one_chip,
+                    lambda x, wg, wu, wd, gs: grouped_ffn(
+                        x, {"w_gate": wg, "w_up": wu, "w_down": wd}, gs,
+                        jax.nn.silu, interpret=False),
+                    ((m, d), VALUES), ((E, d, f), VALUES), ((E, d, f), VALUES),
+                    ((E, f, d), VALUES), ((E,), jnp.int32))
+    # the benchmark's expert roofline finds the kernel by this name
+    assert "expert_gmm" in text
