@@ -258,6 +258,19 @@ def loss_fn(params, batch, cfg: ModelConfig, *, moe_impl="scatter"):
 # ---------------------------------------------------------------------------
 # decode
 # ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnums=0)
+def _stack_layers(n, cache):
+    """``n`` copies of a one-layer cache, stacked on a new leading axis.
+
+    One jitted call whose only outputs are the stacked buffers, so the
+    device holds each stacked cache once while it is built. Built eagerly,
+    ``zeros + cache`` holds the zeros and the sum at once, twice the pool:
+    with the serving steps updating the pool in place, that transient would
+    set the device's memory peak.
+    """
+    return jax.tree.map(lambda l: jnp.broadcast_to(l, (n,) + l.shape), cache)
+
+
 def init_decode_state(cfg: ModelConfig, batch, max_len, *, enc_len=None):
     """Contiguous per-slot decode state. With a quantized ``cfg.kv_dtype``
     the attention-kind caches hold codes + parallel float32 scale buffers
@@ -270,8 +283,7 @@ def init_decode_state(cfg: ModelConfig, batch, max_len, *, enc_len=None):
     dt = _dtype(cfg)
 
     def stacked_cache(kind, n):
-        one = block_init_cache(cfg, kind, batch, max_len, dt)
-        return jax.tree.map(lambda l: jnp.zeros((n,) + l.shape, l.dtype) + l, one)
+        return _stack_layers(n, block_init_cache(cfg, kind, batch, max_len, dt))
 
     if cfg.encoder_layers:
         hd = cfg.resolved_head_dim()
@@ -310,8 +322,8 @@ def init_paged_state(cfg: ModelConfig, slots, pool_blocks, page_size):
     nu = _n_cache_units(cfg)
 
     def stacked_cache(kind):
-        one = block_init_paged_cache(cfg, kind, pool_tokens, slots, dt)
-        return jax.tree.map(lambda l: jnp.zeros((nu,) + l.shape, l.dtype) + l, one)
+        return _stack_layers(
+            nu, block_init_paged_cache(cfg, kind, pool_tokens, slots, dt))
 
     return {
         "caches": tuple(stacked_cache(kind) for kind in _unit(cfg)),
@@ -413,12 +425,14 @@ def _scan_unit_caches(params, caches, x, cfg, step_fn):
     """Run the layer stack with the KV caches riding the scan carry: the
     leading dense layers (``params["lead"]``), then the units.
 
-    Caches are updated with dynamic-update-slice at the layer's index: with
-    donated state buffers this is a true in-place update (the previous
-    xs->ys restacking materialized the whole stacked cache twice per token —
-    §Perf gemma decode). The leading layers' caches are written back after
-    the scan: written before it, the stacked caches would take a third copy
-    on the device between the two. ``step_fn(p_block, cache_block, x, kind)
+    Caches are updated with dynamic-update-slice at the layer's index. The
+    serving engine donates ``state`` to every jitted step
+    (``serve.engine.jit_serve_steps``), so XLA aliases the returned caches
+    to the passed ones and the update is in place: no copy of the stacked
+    pool enters or leaves the step. A caller that does not donate pays one
+    whole copy of every stacked cache per call. The leading layers' caches
+    are written back after the scan: written before it, the stacked caches
+    would take a third copy on the device between the two. ``step_fn(p_block, cache_block, x, kind)
     -> (new_cache, x)`` supplies the per-block computation; prefill, decode,
     and their paged variants all share this scan.
     """

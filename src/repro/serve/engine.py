@@ -256,6 +256,37 @@ class Request:
     registered_blocks: int = 0  # full pages of this slot already indexed
 
 
+def jit_serve_steps(cfg, *, page_size: int = 0):
+    """The engine's jitted ``(prefill, decode)`` steps for ``cfg``: the
+    paged layout's with ``page_size`` pages, the contiguous layout's when
+    ``page_size`` is 0.
+
+    Both donate ``state`` (argument 1), and never ``params``: XLA aliases
+    the returned state to the passed one, so each tick updates the KV pool
+    in place and the device holds it once. The passed tree is deleted by
+    the call, so a caller keeps only the tree the call returned
+    (DESIGN.md §6).
+    """
+    # named, so the device trace's modules read jit_serve_*
+    if page_size:
+        def serve_decode_step(params, state, toks, lens, bt):
+            return decode_step_paged(params, state, toks, lens, bt, cfg,
+                                     page_size=page_size)
+
+        def serve_prefill_step(params, state, toks, lens, nv, bt):
+            return prefill_paged(params, state, toks, lens, nv, bt, cfg,
+                                 page_size=page_size)
+    else:
+        def serve_decode_step(params, state, toks, lens):
+            return decode_step(params, state, toks, lens, cfg)
+
+        def serve_prefill_step(params, state, toks, lens, nv):
+            return prefill(params, state, toks, lens, nv, cfg)
+
+    return (jax.jit(serve_prefill_step, donate_argnums=1),
+            jax.jit(serve_decode_step, donate_argnums=1))
+
+
 class ServeEngine:
     def __init__(self, params, cfg, *, slots: int = 8, max_len: int = 512,
                  chunk_size: int = 64, temperature: float = 0.0,
@@ -366,31 +397,25 @@ class ServeEngine:
 
             # named, so the device trace's modules read jit_serve_*
             def serve_cow_copy(state, src, dst):
-                return copy_paged_block(state, self.cfg, src, dst,
-                                        page_size=ps)
+                return copy_paged_block(state, cfg, src, dst, page_size=ps)
 
-            def serve_decode_step(params, state, toks, lens, bt):
-                return decode_step_paged(params, state, toks, lens, bt,
-                                         self.cfg, page_size=ps)
+            def serve_poison_block(state, block):
+                return poison_paged_block(state, cfg, block, page_size=ps)
 
-            def serve_prefill_step(params, state, toks, lens, nv, bt):
-                return prefill_paged(params, state, toks, lens, nv, bt,
-                                     self.cfg, page_size=ps)
+            def serve_scrub_block(state, block):
+                return poison_paged_block(state, cfg, block, page_size=ps,
+                                          value=0)
 
-            self._cow_copy = jax.jit(serve_cow_copy)
+            # each donates the state, as the steps below do
+            self._cow_copy, self._poison, self._scrub = (
+                jax.jit(f, donate_argnums=0) for f in
+                (serve_cow_copy, serve_poison_block, serve_scrub_block))
         else:
             self.page_size = 0
             self.pool = None
             self.state = init_decode_state(cfg, slots, max_len)
-
-            def serve_decode_step(params, state, toks, lens):
-                return decode_step(params, state, toks, lens, self.cfg)
-
-            def serve_prefill_step(params, state, toks, lens, nv):
-                return prefill(params, state, toks, lens, nv, self.cfg)
-
-        self._decode = jax.jit(serve_decode_step)
-        self._prefill = jax.jit(serve_prefill_step)
+        self._prefill, self._decode = jit_serve_steps(cfg,
+                                                      page_size=self.page_size)
         self.lengths = np.zeros((slots,), np.int32)
         self.cur_tok = np.zeros((slots,), np.int32)
         self.requests: list[Request | None] = [None] * slots
@@ -406,8 +431,6 @@ class ServeEngine:
         self.deadlines = DeadlineWatchdog()
         self._rids: set = set()     # every rid ever submitted (dup check)
         self._next_rid = 0          # auto-assigned rids are monotonic
-        self._poison = None         # lazily jitted kv_corrupt injector half
-        self._scrub = None          # lazily jitted quarantine page scrubber
         # lifecycle counters live in the metrics registry (single-owner
         # contract, §12); the legacy attribute names are properties below
         m = self.metrics
@@ -430,6 +453,12 @@ class ServeEngine:
             for reason in FINISH_REASONS
         }
         self._c_quarantined = m.counter("serve_requests_quarantined_total")
+        # every jitted call that takes and returns the state donates it;
+        # "copied" counts calls that found it still referenced (§6)
+        self._c_donated = m.counter("serve_state_donations_total",
+                                    outcome="donated")
+        self._c_copied = m.counter("serve_state_donations_total",
+                                   outcome="copied")
         self._g_peak_active = m.gauge("serve_peak_active_tokens")
         self._g_peak_kv = m.gauge("serve_peak_kv_used_tokens")
         self._g_queue = m.gauge("serve_queue_depth")
@@ -868,7 +897,7 @@ class ServeEngine:
                     f"left to evict; raise pool_blocks")
             self._preempt(victim)
         src, dst = pair
-        self.state = self._cow_copy(self.state, src, dst)
+        self._update_state(self._cow_copy, src, dst)
         if self.metrics.trace:
             self.metrics.instant("cow_copy", pid=PID_REQUESTS,
                                  tid=self.requests[s].rid, step=self.ticks,
@@ -934,6 +963,22 @@ class ServeEngine:
         ]
         return jnp.stack(keys)
 
+    def _update_state(self, fn, *args):
+        """``self.state = fn(self.state, *args)`` for a jitted ``fn`` that
+        donates the state it is given."""
+        state = self.state
+        self.state = fn(state, *args)
+        self._count_donation(state)
+
+    def _count_donation(self, passed):
+        """Count whether the jitted call that took the state tree
+        ``passed`` updated it in place. A donated buffer is deleted as the
+        call is dispatched, so this reads no device value and waits for
+        nothing; a buffer still referenced elsewhere (a NumPy view of it,
+        say) cannot be donated, and XLA copies the pool instead."""
+        donated = jax.tree.leaves(passed)[0].is_deleted()
+        (self._c_donated if donated else self._c_copied).inc()
+
     def _register_active_pages(self):
         for s in range(self.slots):
             if self.requests[s] is not None:
@@ -950,12 +995,7 @@ class ServeEngine:
         same tick, which is what the quarantine sentinel must catch."""
         idx = max(0, (int(self.lengths[s]) - 1) // self.page_size)
         block = int(self.pool.tables[s, idx])
-        if self._poison is None:
-            ps = self.page_size
-            self._poison = jax.jit(
-                lambda state, b: poison_paged_block(
-                    state, self.cfg, b, page_size=ps))
-        self.state = self._poison(self.state, jnp.int32(block))
+        self._update_state(self._poison, jnp.int32(block))
 
     def _scrub_slot(self, s):
         """Zero the physical pages a quarantined slot solely owns before
@@ -965,15 +1005,10 @@ class ServeEngine:
         poisoned page would corrupt its next owner's logits mid-page.
         Shared pages (refcount > 1) are skipped: another live reference
         holds valid content there and this slot never wrote them."""
-        if self._scrub is None:
-            ps = self.page_size
-            self._scrub = jax.jit(
-                lambda state, b: poison_paged_block(
-                    state, self.cfg, b, page_size=ps, value=0))
         for i in range(int(self.pool.n_blocks[s])):
             b = int(self.pool.tables[s, i])
             if int(self.pool.refcount[b]) == 1:
-                self.state = self._scrub(self.state, jnp.int32(b))
+                self._update_state(self._scrub, jnp.int32(b))
 
     def _chaos_logits(self, active, logits):
         """logits chaos: overwrite an injected slot's logits row with NaN
@@ -1054,6 +1089,7 @@ class ServeEngine:
         with m.span("serve.dispatch." + kind, active=len(active)):
             logits, self.state = (self._prefill if prefilling
                                   else self._decode)(*args)
+            self._count_donation(args[1])
         with m.span("serve.sample"):
             logits = self._chaos_logits(active, logits)
             nxt = sample_tokens(self._sample_keys(), logits,
@@ -1207,6 +1243,8 @@ class ServeEngine:
         snap["finish_reasons"] = {
             reason: c.value for reason, c in self._c_reason.items()}
         snap["quarantined"] = self._c_quarantined.value
+        snap["state_donations"] = {"donated": self._c_donated.value,
+                                   "copied": self._c_copied.value}
         return snap
 
     # -- memory accounting (BENCH_serve.json) -------------------------------

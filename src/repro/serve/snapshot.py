@@ -85,7 +85,10 @@ def save_snapshot(engine, path: str) -> dict:
     """Write a crash-consistent snapshot of ``engine`` to ``path``.
 
     Returns the JSON-able meta header (useful for logging/benching).
-    Must be called between ticks — never from inside a tick.
+    Must be called between ticks — never from inside a tick. The host
+    copies of the state are dropped before it returns: on a CPU they are
+    views of the device buffers, and a view left alive would keep the next
+    tick from donating the state (DESIGN.md §6).
     """
     leaves, _ = jax.tree.flatten(engine.state)
     host = [np.asarray(x) for x in leaves]
@@ -199,14 +202,18 @@ def restore_engine(path: str, params, cfg, *, metrics=None, trace=False):
                                              meta["leaves"])):
         got = _view_back(raw, spec["dtype"], spec["shape"])
         if (tuple(got.shape) != tuple(ref.shape)
-                or str(got.dtype) != str(np.asarray(ref).dtype)):
+                or str(got.dtype) != str(ref.dtype)):
             raise ValueError(
                 f"snapshot leaf {i} is {spec['dtype']}{spec['shape']} but "
-                f"the rebuilt engine expects "
-                f"{np.asarray(ref).dtype}{list(ref.shape)}; "
+                f"the rebuilt engine expects {ref.dtype}{list(ref.shape)}; "
                 f"config/checkpoint mismatch")
-        leaves.append(jnp.asarray(got))
-    engine.state = jax.tree.unflatten(treedef, leaves)
+        leaves.append(got)
+    # free the rebuilt engine's empty pool before placing the snapshot's:
+    # the device holds one state at a time (DESIGN.md §6)
+    for ref in fresh:
+        ref.delete()
+    engine.state = jax.tree.unflatten(treedef,
+                                      [jnp.asarray(x) for x in leaves])
 
     keydata = _view_back(raw_key, meta["key_dtype"], meta["key_shape"])
     engine.key = (jax.random.wrap_key_data(jnp.asarray(keydata))

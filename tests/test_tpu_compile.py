@@ -204,3 +204,55 @@ def test_expert_gmm_compiles(one_chip, rows):
                     ((E, f, d), VALUES), ((E,), jnp.int32))
     # the benchmark's expert roofline finds the kernel by this name
     assert "expert_gmm" in text
+
+
+# the engine's own serving steps as qwen1.5-0.5b is benchmarked
+# (bench/configs): bf16, the paged ExpMul kernels, 16 slots of 3072 tokens
+SERVED = dict(slots=16, max_len=3072)
+
+
+@pytest.mark.parametrize("step", ("prefill", "decode"))
+def test_serve_steps_update_the_pool_in_place(one_chip, monkeypatch, step):
+    """The jitted steps donate the state: XLA aliases every state byte to
+    the output, and no copy of a stacked pool (a whole layer stack's K or
+    V, 2.4 GB each here) enters the step."""
+    from repro.models.api import init_model, init_paged_state
+    from repro.serve.engine import jit_serve_steps
+
+    # the kernels choose interpret mode from the platform, which is the
+    # CPU here: compile them as the chip runs them
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("qwen1.5-0.5b").replace(
+        dtype="bfloat16", param_dtype="bfloat16", attention_impl="pallas",
+        attention_variant="expmul")
+    slots, mb = SERVED["slots"], SERVED["max_len"] // PS
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(lambda k: init_model(k, cfg),
+                                    jax.random.PRNGKey(0)))
+    state = on_chip(jax.eval_shape(
+        lambda: init_paged_state(cfg, slots, slots * mb, PS)))
+    prefill, decode = jit_serve_steps(cfg, page_size=PS)
+    fn, rest = {
+        "prefill": (prefill, (i32(slots, C), i32(slots), i32(slots),
+                              i32(slots, mb))),
+        "decode": (decode, (i32(slots), i32(slots), i32(slots, mb))),
+    }[step]
+    compiled = fn.lower(params, state, *rest).compile()
+    leaves = jax.tree.leaves(state)
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in leaves)
+    text = compiled.as_text()
+    assert "paged_" + step + "_fwd_pallas" in text
+    for a in leaves:
+        assert a.dtype == jnp.bfloat16
+        pool = f"bf16[{','.join(map(str, a.shape))}]"
+        copies = [line for line in text.splitlines()
+                  if f"= {pool}{{" in line and " copy(" in line]
+        assert not copies, f"the step copies a stacked pool: {copies[0]}"
